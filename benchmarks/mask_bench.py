@@ -14,8 +14,9 @@ artifact, alongside ``BENCH_decode.json``):
    vocab sizes (gemma3 V=262144: 256 KiB -> 32 KiB per row).  Asserts
    the >=8x reduction the tentpole claims.
  - **parity**: the packed kernel is bitwise-identical to the int8-mask
-   kernel and the jnp oracle on masks taken from the real decoder
-   states (plus empty/single-bit rows), including an odd-V tail tile.
+   entry point (packed on device) and the jnp oracle on masks taken
+   from the real decoder states (plus empty/single-bit rows), including
+   an odd-V tail tile.
 
 Pure host + interpret-mode work: no model, no training, fast enough for
 a CI smoke step.
@@ -137,7 +138,8 @@ def run_bytes(verbose: bool = True):
 
 
 def run_parity(masks, verbose: bool = True):
-    """Packed kernel == int8 kernel == oracle on real grammar masks."""
+    """Packed kernel == int8 entry point == oracle on real grammar
+    masks."""
     rng = np.random.default_rng(3)
     out = {}
     for case, v, bv in (("even", VOCAB_SIZE, 128), ("odd_tail", 420, 128)):

@@ -12,6 +12,8 @@ import time
 
 
 def main() -> None:
+    from repro.launch import compile_cache
+    compile_cache.enable()
     from benchmarks import (fig2_template, fig5_speculation, kernel_bench,
                             mask_bench, precompute_cost, serving_bench,
                             table2_invasiveness, table2b_ner,

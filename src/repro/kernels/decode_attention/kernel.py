@@ -29,9 +29,14 @@ scalar operands ride the scalar-prefetch channel, which is what lets the
 pipeline compute the next DMA's source address from the table before the
 tile is needed.
 
-Grid (B, G, T/BLOCK_T) — the T axis is minor, so VMEM scratch (m, l, acc)
-carries across cache tiles of one (batch, group).  Raggedness is handled
-twice over:
+Grid (B, T/BLOCK_T) — the T axis is minor, so VMEM scratch (m, l, acc)
+carries across cache tiles of one row.  Each grid step loads ALL G heads
+of its tile, block (1, BLOCK_T, G, D): the TPU compiler requires the
+last two block dims to be (8, 128)-divisible or whole, and a one-head
+block (1, BLOCK_T, 1, D) is neither once G > 1.  The heads are then
+walked by a static in-kernel loop, each with its own scratch slice, so
+every head's arithmetic is exactly the one-head computation.  Raggedness
+is handled twice over:
   * ``pl.when(j * BLOCK_T < lengths[b] + S - 1)`` skips compute on tiles
     fully beyond the row's frontier, and
   * the K/V index maps clamp the tile index to the row's last live tile
@@ -39,10 +44,11 @@ twice over:
     pipeline re-addresses the same block and elides the HBM copy —
     row b moves ceil((lengths[b]+S-1)/BLOCK_T) tiles, not T/BLOCK_T.
 
-VMEM working set per step: BLOCK_T*(Dk+Dv) halves of K/V + S*Qh*(Dv+2)
-f32 accumulators — with Dk=Dv=128, BLOCK_T=512, S*Qh<=32: ~600 KiB,
-comfortably inside the ~16 MiB VMEM budget; BLOCK_T (== page_size when
-paged) is the §Perf tuning knob.
+VMEM working set per step: BLOCK_T*G*(Dk+Dv) halves of K/V, double
+buffered, + G*S*Qh*(Dv+2) f32 accumulators.  Dense caches cap BLOCK_T so
+one K/V block stays within ``KV_BLOCK_BYTES``; paged caches use
+BLOCK_T == page_size (stablelm-1.6b, ps=64, G=32, D=64 in bf16: 256 KiB
+per block, lane-padded to 512 KiB).
 """
 from __future__ import annotations
 
@@ -54,11 +60,14 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG = -1e30
+# VMEM budget for one dense K/V block (BLOCK_T tokens x all G heads); the
+# pipeline double-buffers K and V, so four such blocks are resident
+KV_BLOCK_BYTES = 1 << 20
 
 
 def _kernel(len_ref, *rest,
-            block_t: int, n_blocks: int, s_win: int, qh: int, scale: float,
-            split_k: bool, paged: bool):
+            block_t: int, n_blocks: int, s_win: int, g: int, qh: int,
+            scale: float, split_k: bool, paged: bool):
     if paged:                               # block table rides the scalar
         rest = rest[1:]                     # channel; index maps consume it
     q_ref, k_ref, v_ref, *rest = rest
@@ -67,7 +76,7 @@ def _kernel(len_ref, *rest,
     else:
         o_ref, m_scr, l_scr, acc_scr = rest
     i = pl.program_id(0)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
     base = len_ref[i]                       # keys visible to window pos 0
     frontier = base + s_win - 1             # keys visible to the last pos
 
@@ -79,41 +88,43 @@ def _kernel(len_ref, *rest,
 
     @pl.when(j * block_t < frontier)        # early-exit past the frontier
     def _accumulate():
-        q = q_ref[0, :, 0].reshape(s_win * qh, q_ref.shape[-1])
-        q = q.astype(jnp.float32)                        # (S*Qh, Dk)
-        k = k_ref[0, :, 0].astype(jnp.float32)           # (BT, Dk)
-        v = v_ref[0, :, 0].astype(jnp.float32)           # (BT, Dv)
-
-        s = jnp.dot(q, k.T)                              # (S*Qh, BT)
-        if split_k:
-            q2 = q2_ref[0, :, 0].reshape(s_win * qh, q2_ref.shape[-1])
-            k2 = k2_ref[0, :, 0].astype(jnp.float32)     # (BT, D2)
-            s = s + jnp.dot(q2.astype(jnp.float32), k2.T)
-        s = s * scale
         t_idx = j * block_t + jax.lax.broadcasted_iota(
             jnp.int32, (1, block_t), 1)
         # row r of the flattened (S*Qh) axis sits at window pos r // Qh
         w_pos = jax.lax.broadcasted_iota(
             jnp.int32, (s_win * qh, 1), 0) // qh
         valid = t_idx < base + w_pos                     # (S*Qh, BT)
-        s = jnp.where(valid, s, NEG)
+        for h in range(g):                  # static: one head per pass
+            q = q_ref[0, :, h].reshape(s_win * qh, q_ref.shape[-1])
+            q = q.astype(jnp.float32)                    # (S*Qh, Dk)
+            k = k_ref[0, :, h].astype(jnp.float32)       # (BT, Dk)
+            v = v_ref[0, :, h].astype(jnp.float32)       # (BT, Dv)
 
-        m_prev = m_scr[...]                              # (S*Qh, 1)
-        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-        # explicit re-mask: on an all-masked row s - m_new == 0, and the
-        # exp would count dead keys into l (divergence vs ref on empty
-        # rows / skipped tiles)
-        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)    # (S*Qh, BT)
-        corr = jnp.exp(m_prev - m_new)                   # (S*Qh, 1)
-        l_scr[...] = l_scr[...] * corr + p.sum(axis=-1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * corr + jnp.dot(p, v)
-        m_scr[...] = m_new
+            s = jnp.dot(q, k.T)                          # (S*Qh, BT)
+            if split_k:
+                q2 = q2_ref[0, :, h].reshape(s_win * qh, q2_ref.shape[-1])
+                k2 = k2_ref[0, :, h].astype(jnp.float32)  # (BT, D2)
+                s = s + jnp.dot(q2.astype(jnp.float32), k2.T)
+            s = s * scale
+            s = jnp.where(valid, s, NEG)
+
+            m_prev = m_scr[h]                            # (S*Qh, 1)
+            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+            # explicit re-mask: on an all-masked row s - m_new == 0, and
+            # the exp would count dead keys into l (divergence vs ref on
+            # empty rows / skipped tiles)
+            p = jnp.where(valid, jnp.exp(s - m_new), 0.0)  # (S*Qh, BT)
+            corr = jnp.exp(m_prev - m_new)               # (S*Qh, 1)
+            l_scr[h] = l_scr[h] * corr + p.sum(axis=-1, keepdims=True)
+            acc_scr[h] = acc_scr[h] * corr + jnp.dot(p, v)
+            m_scr[h] = m_new
 
     @pl.when(j == n_blocks - 1)
     def _done():
-        out = acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, :, 0] = out.reshape(
-            s_win, qh, o_ref.shape[-1]).astype(o_ref.dtype)
+        for h in range(g):
+            out = acc_scr[h] / jnp.maximum(l_scr[h], 1e-30)
+            o_ref[0, :, h] = out.reshape(
+                s_win, qh, o_ref.shape[-1]).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit,
@@ -151,6 +162,10 @@ def decode_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         block_tables = jnp.asarray(block_tables, jnp.int32)
     else:
         t = k.shape[1]
+        # one token of a K block is G lane-padded rows in VMEM
+        tok_bytes = g * -(-max(dk, dv) // 128) * 128 * k.dtype.itemsize
+        while block_t > 8 and block_t * tok_bytes > KV_BLOCK_BYTES:
+            block_t //= 2
         if t % block_t != 0:
             block_t = t
         n_blocks = t // block_t
@@ -160,7 +175,7 @@ def decode_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         jnp.asarray(lengths, jnp.int32).reshape(-1), (b,))
     split_k = q2 is not None
     kernel = functools.partial(_kernel, block_t=block_t, n_blocks=n_blocks,
-                               s_win=s_win, qh=qh, scale=scale,
+                               s_win=s_win, g=g, qh=qh, scale=scale,
                                split_k=split_k, paged=paged)
 
     def last_live(i, len_ref):
@@ -169,36 +184,36 @@ def decode_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         return jnp.maximum(len_ref[i] + s_win - 2, 0) // block_t
 
     if paged:
-        def kv_map(i, h, j, len_ref, tbl_ref):
+        def kv_map(i, j, len_ref, tbl_ref):
             page = jnp.minimum(j, last_live(i, len_ref))
-            return (jnp.maximum(tbl_ref[i, page], 0), 0, h, 0)
+            return (jnp.maximum(tbl_ref[i, page], 0), 0, 0, 0)
     else:
-        def kv_map(i, h, j, len_ref):
-            return (i, jnp.minimum(j, last_live(i, len_ref)), h, 0)
+        def kv_map(i, j, len_ref):
+            return (i, jnp.minimum(j, last_live(i, len_ref)), 0, 0)
 
-    def q_map(i, h, j, *_):
-        return (i, 0, h, 0, 0)
+    def q_map(i, j, *_):
+        return (i, 0, 0, 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, s_win, 1, qh, dk), q_map),
-        pl.BlockSpec((1, block_t, 1, dk), kv_map),
-        pl.BlockSpec((1, block_t, 1, dv), kv_map),
+        pl.BlockSpec((1, s_win, g, qh, dk), q_map),
+        pl.BlockSpec((1, block_t, g, dk), kv_map),
+        pl.BlockSpec((1, block_t, g, dv), kv_map),
     ]
     operands = [lengths] + ([block_tables] if paged else []) + [q, k, v]
     if split_k:
         d2 = q2.shape[-1]
-        in_specs += [pl.BlockSpec((1, s_win, 1, qh, d2), q_map),
-                     pl.BlockSpec((1, block_t, 1, d2), kv_map)]
+        in_specs += [pl.BlockSpec((1, s_win, g, qh, d2), q_map),
+                     pl.BlockSpec((1, block_t, g, d2), kv_map)]
         operands += [q2, k2]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2 if paged else 1,
-        grid=(b, g, n_blocks),
+        grid=(b, n_blocks),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, s_win, 1, qh, dv), q_map),
+        out_specs=pl.BlockSpec((1, s_win, g, qh, dv), q_map),
         scratch_shapes=[
-            pltpu.VMEM((s_win * qh, 1), jnp.float32),
-            pltpu.VMEM((s_win * qh, 1), jnp.float32),
-            pltpu.VMEM((s_win * qh, dv), jnp.float32),
+            pltpu.VMEM((g, s_win * qh, 1), jnp.float32),
+            pltpu.VMEM((g, s_win * qh, 1), jnp.float32),
+            pltpu.VMEM((g, s_win * qh, dv), jnp.float32),
         ],
     )
     out = pl.pallas_call(

@@ -5,11 +5,12 @@ to the interpreted kernel (CPU validation) elsewhere; ``use_ref=True``
 selects the unfused jnp oracle (the baseline the §Perf analysis compares
 against).
 
-The mask operand picks the kernel layout by dtype: uint32 means a packed
+The mask operand's layout is read from its dtype: uint32 means a packed
 ``(B, ceil(V/32))`` bitset row (``core/bitmask.py`` wire format, unpacked
-in-register by the kernel); anything else is the legacy ``(B, V)``
-int8/bool mask.  Both layouts are bitwise-identical in output — asserted
-by the parity tests and by ``benchmarks/mask_bench.py``.
+in-register by the kernel); anything else is a dense ``(B, V)`` int8/bool
+mask, packed on device before the same kernel.  Both layouts are
+bitwise-identical in output — asserted by the parity tests and by
+``benchmarks/mask_bench.py``.
 
 ``masked_sample_packed(logits, bits, temps, keys)`` is the device-side
 temperature>0 selection path (ISSUE 8 satellite): masked softmax sampling
@@ -28,12 +29,14 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.masked_sample.kernel import (NEG, masked_argmax_pallas,
+from repro.kernels.masked_sample.kernel import (NEG, TILE_V,
+                                                masked_argmax_pallas,
                                                 masked_argmax_pallas_packed)
 from repro.kernels.masked_sample.ref import masked_argmax_ref
 
 
-def masked_argmax(logits, mask, use_ref: bool = False, block_v: int = 2048):
+def masked_argmax(logits, mask, use_ref: bool = False,
+                  block_v: int = TILE_V):
     if use_ref:
         return masked_argmax_ref(logits, mask)
     on_tpu = jax.default_backend() == "tpu"

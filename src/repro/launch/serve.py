@@ -1,5 +1,7 @@
-"""Constrained-serving driver: loads (or trains) a small model and serves
-batched requests through the per-request constraint API.
+"""Constrained-serving entry point: builds a model (the published config
+at full width with seeded random weights, its ``--smoke`` variant, or a
+``--checkpoint``) and serves batched requests through the per-request
+constraint API.
 
 ``--grammar`` takes a comma-separated list ("none" = unconstrained rows);
 every listed grammar is registered on ONE engine's grammar registry and
@@ -10,12 +12,23 @@ mixed-grammar traffic:
       --grammar json,c,none --mode domino --prompts 6
 """
 import argparse
+import dataclasses
+from typing import List, Optional, Sequence, Tuple
+
+BASE_PROMPTS = ("A person encoded as a JSON object: ",
+                "Results: ",
+                "Config: ",
+                "Data record: ")
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="stablelm-1.6b")
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action="store_true",
+                    help="serve the reduced smoke variant of --arch, with "
+                         "its vocabulary collapsed to the tokenizer's "
+                         "(default: the published config at full width "
+                         "and vocabulary, random weights from PRNGKey(0))")
     ap.add_argument("--grammar", default="json",
                     help="comma-separated grammar names cycled across "
                          "prompts; 'none' entries serve unconstrained rows")
@@ -101,24 +114,32 @@ def main() -> None:
                          "<token ids...>' line per result (restart-smoke "
                          "bitwise comparison)")
     ap.add_argument("--checkpoint", default=None)
-    args = ap.parse_args()
+    return ap
 
-    import dataclasses
 
+def grammar_names(args) -> List[str]:
+    """The ``--grammar`` list in order ("none" = unconstrained rows)."""
+    return [n.strip() for n in args.grammar.split(",") if n.strip()]
+
+
+def build_engine(args, init_seed: int = 0):
+    """One engine for ``args``: config, seeded tokenizer (or checkpoint),
+    model and params (random weights from ``PRNGKey(init_seed)``), with
+    every listed grammar registered on its registry and precomputed."""
     import jax
 
     from repro.configs import get_config
     from repro.core import grammars
     from repro.core.sampling import GrammarSampler
     from repro.models import build_model
-    from repro.serving import (ConstraintSpec, DecodeParams, Request,
-                               ServingEngine, TokenJournal)
+    from repro.serving import ServingEngine
     from repro.tokenizer import BPETokenizer, train_bpe
     from repro.training import checkpoint
 
-    gnames = [n.strip() for n in args.grammar.split(",") if n.strip()]
-    loaded = {n: grammars.load(n) for n in gnames if n != "none"}
-    cfg = get_config(args.arch, smoke=True)
+    loaded = {n: grammars.load(n) for n in grammar_names(args)
+              if n != "none"}
+    cfg = get_config(args.arch, smoke=args.smoke)
+    cfg = dataclasses.replace(cfg, use_pallas_kernels=args.kernels)
     if args.checkpoint:
         import os
         tok = BPETokenizer.load(os.path.join(args.checkpoint,
@@ -127,7 +148,7 @@ def main() -> None:
                                   max_seq_len=4096)
         model = build_model(cfg)
         params, _, _ = checkpoint.load(
-            args.checkpoint, model.init(jax.random.PRNGKey(0)))
+            args.checkpoint, model.init(jax.random.PRNGKey(init_seed)))
     else:
         corpus = b""
         for i, g in enumerate(loaded.values() or
@@ -135,30 +156,82 @@ def main() -> None:
             corpus += GrammarSampler(g, seed=i).corpus(
                 200 // max(1, len(loaded)))
         tok = train_bpe(corpus, vocab_size=400)
-        cfg = dataclasses.replace(cfg, vocab_size=tok.vocab_size,
-                                  max_seq_len=4096)
+        if args.smoke:
+            cfg = dataclasses.replace(cfg, vocab_size=tok.vocab_size,
+                                      max_seq_len=4096)
+        # at full width the head keeps the published vocabulary; the
+        # engine slices logits to the tokenizer's ids
         model = build_model(cfg)
-        params = model.init(jax.random.PRNGKey(0))
-
-    if args.kernels:
-        cfg = dataclasses.replace(cfg, use_pallas_kernels=True)
-        model = build_model(cfg)
+        params = model.init(jax.random.PRNGKey(init_seed))
 
     # ONE engine, one KV pool: constraints ride on each Request
-    device_tables = args.device_tables or args.device_loop
     engine = ServingEngine(model, params, tok, max_len=1024,
                            analysis_policy=args.analyze,
-                           device_tables=device_tables)
+                           device_tables=args.device_tables
+                           or args.device_loop)
     for name, g in loaded.items():
         engine.register_grammar(name, g)   # analyzed per --analyze policy
     engine.precompute()                 # warm every registered grammar
+    return engine
+
+
+def make_requests(args, prompts: Sequence[str] = BASE_PROMPTS
+                  ) -> Tuple[list, List[str]]:
+    """``args.prompts`` requests cycling through ``prompts`` and the
+    ``--grammar`` list; returns (requests, grammar label per request)."""
+    from repro.serving import ConstraintSpec, DecodeParams, Request
+
+    gnames = grammar_names(args)
+    decode = DecodeParams(
+        temperature=args.temperature, max_tokens=args.max_tokens,
+        speculative=args.speculative, spec_s=args.spec_s,
+        deadline_s=args.deadline_s)
+    specs = []
+    for name in gnames:
+        if name == "none" or args.mode == "unconstrained":
+            specs.append(ConstraintSpec())
+        else:
+            specs.append(ConstraintSpec(
+                grammar=name, mode=args.mode,
+                k=(None if args.k < 0 else args.k),
+                opportunistic=args.opportunistic))
+    requests = [
+        Request(prompts[i % len(prompts)], specs[i % len(specs)],
+                dataclasses.replace(decode, seed=args.seed + i))
+        for i in range(args.prompts)]
+    labels = [gnames[i % len(gnames)] for i in range(args.prompts)]
+    return requests, labels
+
+
+def serve_batch(engine, requests, args, journal=None):
+    """Serve ``requests`` through continuous batching with ``args``'
+    slots, KV layout, queue and fused-loop options."""
+    return engine.generate_batch(
+        requests, max_batch=args.slots,
+        paged=False if args.no_paged else None,
+        page_size=args.page_size, n_pages=args.pool_pages,
+        queue_limit=args.queue_limit,
+        device_loop=args.device_loop, sync_n=args.sync_n,
+        journal=journal, prefix_cache=args.prefix_cache)
+
+
+def main(argv: Optional[Sequence[str]] = None) -> None:
+    ap = build_parser()
+    args = ap.parse_args(argv)
+
+    from repro.launch import compile_cache
+    from repro.serving import TokenJournal
+
+    compile_cache.enable()
+    engine = build_engine(args)
+    loaded = [n for n in grammar_names(args) if n != "none"]
     for name, rep in engine.analysis_reports.items():
         print(f"[analysis] {name}: "
               f"{'OK' if rep.ok() else 'PROBLEMS'} "
               f"({rep.closure.n_states} states, "
               f"{'finite' if rep.closure.finite else 'open'}, "
               f"{rep.analysis_time_s:.2f}s)")
-    if device_tables:
+    if engine.enable_device_tables:
         for name, tbl in engine.device_tables.items():
             print(f"[device-table] {name}: {tbl.n_states} states, "
                   f"{tbl.n_bytes / 1024:.0f} KiB uploaded")
@@ -200,31 +273,7 @@ def main() -> None:
                     str(t) for t in s.result.token_ids))
         return
 
-    decode = DecodeParams(
-        temperature=args.temperature, max_tokens=args.max_tokens,
-        speculative=args.speculative, spec_s=args.spec_s,
-        deadline_s=args.deadline_s)
-    specs = []
-    for name in gnames:
-        if name == "none" or args.mode == "unconstrained":
-            specs.append(ConstraintSpec())
-        else:
-            specs.append(ConstraintSpec(
-                grammar=name, mode=args.mode,
-                k=(None if args.k < 0 else args.k),
-                opportunistic=args.opportunistic))
-
-    base_prompts = ["A person encoded as a JSON object: ",
-                    "Results: ",
-                    "Config: ",
-                    "Data record: "]
-    requests = [
-        Request(base_prompts[i % len(base_prompts)],
-                specs[i % len(specs)],
-                dataclasses.replace(decode, seed=args.seed + i))
-        for i in range(args.prompts)]
-    labels = [gnames[i % len(gnames)] for i in range(args.prompts)]
-
+    requests, labels = make_requests(args)
     if len(requests) > 1 or journal is not None:
         # continuous batching covers every arch (SSM/SWA rows are admitted
         # by exact-length prefill; speculation refeeds per row); pure
@@ -234,13 +283,8 @@ def main() -> None:
               f"({','.join(sorted(set(labels)))}), "
               f"{min(len(requests), args.slots)} slots, "
               f"{'contiguous KV' if args.no_paged else 'paged KV'}]")
-        results = engine.generate_batch(
-            requests, max_batch=args.slots,
-            paged=False if args.no_paged else None,
-            page_size=args.page_size, n_pages=args.pool_pages,
-            queue_limit=args.queue_limit,
-            device_loop=args.device_loop, sync_n=args.sync_n,
-            journal=journal, prefix_cache=args.prefix_cache)
+        results = serve_batch(engine, requests, args, journal)
+        print(f"[stats] {engine.last_batch_stats}")
     else:
         results = [engine.generate(r) for r in requests]
     for lbl, req, r in zip(labels, requests, results):

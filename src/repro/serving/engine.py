@@ -263,6 +263,8 @@ class ServingEngine:
         # prefills and PINS their full KV pages at warm() time, so the
         # very first live request sharing the preamble already hits
         self.pinned_prompts: List[str] = []
+        # counters of the last generate_batch call's scheduler
+        self.last_batch_stats: Optional[Dict[str, Any]] = None
         # engine-level rng: used only by the template baseline (which has
         # no Request); request sampling is per-session
         self.rng = np.random.default_rng(self.cfg.seed)
@@ -909,6 +911,10 @@ class ServingEngine:
         copy-on-write refcounting — admissions skip prefill for the
         cached prefix and re-prefill only the tail (observationally
         pure: outputs are bitwise-identical to a cold cache).
+
+        The scheduler's operational counters (``stats()``: degrades,
+        retries, watchdog trips, engine resets, prefix hits) are kept in
+        ``self.last_batch_stats``.
         """
         from repro.serving.scheduler import ContinuousBatchingScheduler
         cap = min(len(requests), max_batch) if max_batch else len(requests)
@@ -934,6 +940,7 @@ class ServingEngine:
             sched._pin_prompts()
         sessions = [sched.submit(r) for r in requests]
         sched.run()
+        self.last_batch_stats = sched.stats()
         return [s.result for s in sessions]
 
     def restore(self, journal_path: str, max_batch: Optional[int] = None,

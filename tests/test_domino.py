@@ -2,7 +2,6 @@
 equality with the online full-vocab baseline, opportunistic checks."""
 import numpy as np
 import pytest
-pytest.importorskip("hypothesis")
 from hypothesis import given, settings, strategies as st
 
 from repro.core import grammars

@@ -1,6 +1,5 @@
 """Grammar parsing + Earley recognizer."""
 import pytest
-pytest.importorskip("hypothesis")
 from hypothesis import given, settings, strategies as st
 
 from repro.core import grammars

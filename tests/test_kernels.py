@@ -2,12 +2,7 @@
 import jax.numpy as jnp
 import numpy as np
 import pytest
-
-try:                                   # only the property test needs it —
-    from hypothesis import given, settings, strategies as st
-    HAVE_HYPOTHESIS = True
-except ImportError:                    # the parity sweeps must still run
-    HAVE_HYPOTHESIS = False
+from hypothesis import given, settings, strategies as st
 
 from repro.kernels.decode_attention.ops import decode_attention
 from repro.kernels.decode_attention.ref import decode_attention_ref
@@ -179,15 +174,9 @@ def test_mamba_scan(b, s, d, n, bd, bs):
                                rtol=1e-4)
 
 
-def _prop_wrap(f):
-    if not HAVE_HYPOTHESIS:
-        return pytest.mark.skip(reason="hypothesis not installed")(f)
-    return settings(max_examples=10, deadline=None)(
-        given(st.integers(1, 3), st.integers(1, 4), st.integers(2, 32))(f))
-
-
-@_prop_wrap
-def test_mamba_scan_property(b=1, chunks=1, d=2):
+@settings(max_examples=10, deadline=None)
+@given(st.integers(1, 3), st.integers(1, 4), st.integers(2, 32))
+def test_mamba_scan_property(b, chunks, d):
     """State continuity: scanning in one go == chunked with carried h."""
     s = chunks * 16
     n = 4

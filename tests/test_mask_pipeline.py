@@ -15,12 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-
-try:                                   # only the property tests need it —
-    from hypothesis import given, settings, strategies as st
-    HAVE_HYPOTHESIS = True
-except ImportError:                    # everything else must still run
-    HAVE_HYPOTHESIS = False
+from hypothesis import given, settings, strategies as st
 
 from repro.configs.base import ModelConfig
 from repro.core import bitmask, grammars
@@ -69,15 +64,9 @@ def test_tail_bits_are_zero():
     assert bits[1] == 1                 # only bit 0 of the tail word
 
 
-def _prop(f):
-    if not HAVE_HYPOTHESIS:
-        return pytest.mark.skip(reason="hypothesis not installed")(f)
-    return settings(max_examples=40, deadline=None)(
-        given(st.integers(1, 260), st.integers(0, 2**32 - 1))(f))
-
-
-@_prop
-def test_pack_unpack_roundtrip_property(v=7, seed=0):
+@settings(max_examples=40, deadline=None)
+@given(st.integers(1, 260), st.integers(0, 2**32 - 1))
+def test_pack_unpack_roundtrip_property(v, seed):
     rng = np.random.default_rng(seed)
     m = rng.random(v) < rng.random()
     bits = bitmask.pack_bool(m)

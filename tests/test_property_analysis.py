@@ -14,8 +14,6 @@
 import random
 
 import numpy as np
-import pytest
-pytest.importorskip("hypothesis")
 from hypothesis import HealthCheck, given, settings, strategies as st
 
 from repro.core.analysis import analyze

@@ -9,8 +9,6 @@
 import random
 
 import numpy as np
-import pytest
-pytest.importorskip("hypothesis")
 from hypothesis import HealthCheck, given, settings, strategies as st
 
 from repro.core.baselines import OnlineParserDecoder

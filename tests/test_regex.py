@@ -2,7 +2,6 @@
 import re as stdre
 
 import pytest
-pytest.importorskip("hypothesis")
 from hypothesis import given, settings, strategies as st
 
 from repro.core.regex import RegexSyntaxError, compile_pattern, literal_dfa

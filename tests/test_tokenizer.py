@@ -1,7 +1,5 @@
 """Byte-level BPE tokenizer."""
-import pytest
 
-pytest.importorskip("hypothesis")
 from hypothesis import given, settings, strategies as st
 
 from repro.tokenizer import BPETokenizer, train_bpe

@@ -36,8 +36,9 @@ import tempfile
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-WORKLOAD = ["--grammar", "json", "--mode", "domino", "--prompts", "3",
-            "--max-tokens", "16", "--slots", "2", "--seed", "0"]
+WORKLOAD = ["--smoke", "--grammar", "json", "--mode", "domino",
+            "--prompts", "3", "--max-tokens", "16", "--slots", "2",
+            "--seed", "0"]
 
 # cache-warm drill: 5 prompts over the 4-entry base-prompt cycle, so at
 # least one prompt repeats verbatim; page size 8 keeps whole pages well
