@@ -1,7 +1,6 @@
 """Mask-pipeline micro-benchmarks (ISSUE 4): packed bitsets end to end.
 
-Three sections, written to ``BENCH_mask.json`` (the CI perf-trajectory
-artifact, alongside ``BENCH_decode.json``):
+Three sections, written to ``BENCH_mask.json`` (a CI artifact):
 
  - **build**: per-step full-mask assembly over real DOMINO states (every
    step of grammar-sampled JSON generations) three ways — the pre-bitset

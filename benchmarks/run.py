@@ -14,10 +14,10 @@ import time
 def main() -> None:
     from repro.launch import compile_cache
     compile_cache.enable()
-    from benchmarks import (fig2_template, fig5_speculation, kernel_bench,
-                            mask_bench, precompute_cost, serving_bench,
-                            table2_invasiveness, table2b_ner,
-                            table3_throughput, table4_lookahead)
+    from benchmarks import (fig2_template, fig5_speculation, mask_bench,
+                            precompute_cost, table2_invasiveness,
+                            table2b_ner, table3_throughput,
+                            table4_lookahead)
     sections = {
         "precompute": precompute_cost.run,
         "table2": table2_invasiveness.run,
@@ -26,9 +26,7 @@ def main() -> None:
         "table4": table4_lookahead.run,
         "fig2": fig2_template.run,
         "fig5": fig5_speculation.run,
-        "kernels": kernel_bench.run,
         "mask": mask_bench.run,
-        "serving": serving_bench.run,
     }
     want = sys.argv[1:] or list(sections)
     print("name,us_per_call,derived")

@@ -210,9 +210,9 @@ def run_serving_fused(model, params, tok, verbose: bool = True):
     reads the cache through the ragged flash-decode kernel) and off (dense
     jnp attention over the full cache).  On CPU the kernel runs
     interpreted, so absolute wall time is NOT the TPU story — the
-    hardware-independent quantity is the per-step cache traffic ratio
-    reported by ``kernel_bench``; this row exists to pin the routing and
-    track the two paths' trajectories."""
+    chip measures the kernel (``bench/run.py``, its
+    ``decode_attention_roofline``); this row exists to pin the routing
+    and track the two paths' trajectories."""
     g = grammars.load("json")
     prompts = [f"request {i}, a JSON value: " for i in range(3)]
     rows = {}

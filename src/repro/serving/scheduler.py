@@ -97,6 +97,13 @@ Design points (ISSUE 1 tentpole):
    and the slot<->session bijection at every tick boundary — surviving
    rows are asserted bitwise-identical to fault-free runs by the chaos
    suite (tests/test_faults.py).
+ - tracing: :func:`~repro.serving.tracing.span` names each phase of a
+   tick — ``serve.tick`` around the whole of ``step()``; ``reap``,
+   ``admit`` (with a ``prefill`` per admitted request), ``readback``,
+   ``select`` (with a ``mask`` per mask build), ``commit`` (with an
+   ``advance`` per checker advance), ``dispatch``, ``prebuild`` and
+   ``wait`` inside it — as host spans that a profiler session records
+   on the device trace's clock.
 
 Token selection is identical to the single-request engine path at
 temperature 0 (greedy masked argmax, ties to the lowest index), so
@@ -130,6 +137,7 @@ from repro.serving.request import (ConstraintSpec, DecodeParams, Request,
                                    select_token)
 from repro.serving.session import GenerationResult, Session
 from repro.serving.supervisor import DegradationSupervisor
+from repro.serving.tracing import span
 
 
 # -- page allocation -----------------------------------------------------------
@@ -670,27 +678,28 @@ class ContinuousBatchingScheduler:
         """One scheduler tick: reap -> admit -> select -> decode.
         Returns sessions that reached a terminal status since the last
         drain (tick casualties and submit-time rejections alike)."""
-        if self.injector is not None:
-            self.injector.begin_tick()
-        self._reap()
-        self._admit()
-        if any(s is not None for s in self.slots):
-            width = self._verify_width()
-            if width > 1:
-                self._spec_step(width)
-            elif self._device_ready():
-                self._device_step()
-            else:
-                self._plain_step()
-        self._reset_vacant_lens()
-        self._journal_tick()
-        self._supervisor_tick()
-        if self.debug_invariants:
-            problems = check_invariants(self)
-            if problems:
-                raise InvariantViolation("; ".join(problems))
-        done, self._finished_now = self._finished_now, []
-        return done
+        with span("tick"):
+            if self.injector is not None:
+                self.injector.begin_tick()
+            self._reap()
+            self._admit()
+            if any(s is not None for s in self.slots):
+                width = self._verify_width()
+                if width > 1:
+                    self._spec_step(width)
+                elif self._device_ready():
+                    self._device_step()
+                else:
+                    self._plain_step()
+            self._reset_vacant_lens()
+            self._journal_tick()
+            self._supervisor_tick()
+            if self.debug_invariants:
+                problems = check_invariants(self)
+                if problems:
+                    raise InvariantViolation("; ".join(problems))
+            done, self._finished_now = self._finished_now, []
+            return done
 
     # -- lifecycle: deadlines / cancellation ------------------------------------
 
@@ -719,23 +728,24 @@ class ContinuousBatchingScheduler:
         per-request deadlines (waiting AND resident), and shed queued
         requests past the queue-wait timeout.  Freed slots and pages are
         available to this very tick's admission."""
-        now = time.perf_counter()
-        if self.waiting:
-            keep: "collections.deque[Session]" = collections.deque()
-            while self.waiting:
-                sess = self.waiting.popleft()
-                status, why = self._overdue(sess, now, waiting=True)
-                if status is None:
-                    keep.append(sess)
-                else:
+        with span("reap"):
+            now = time.perf_counter()
+            if self.waiting:
+                keep: "collections.deque[Session]" = collections.deque()
+                while self.waiting:
+                    sess = self.waiting.popleft()
+                    status, why = self._overdue(sess, now, waiting=True)
+                    if status is None:
+                        keep.append(sess)
+                    else:
+                        self._finish(sess, status=status, error=why)
+                self.waiting = keep
+            for sess in list(self.slots):
+                if sess is None:
+                    continue
+                status, why = self._overdue(sess, now, waiting=False)
+                if status is not None:
                     self._finish(sess, status=status, error=why)
-            self.waiting = keep
-        for sess in list(self.slots):
-            if sess is None:
-                continue
-            status, why = self._overdue(sess, now, waiting=False)
-            if status is not None:
-                self._finish(sess, status=status, error=why)
 
     def _verify_width(self) -> int:
         """Speculative verify width for this tick: 1 + the widest
@@ -769,130 +779,139 @@ class ContinuousBatchingScheduler:
         return None
 
     def _admit(self) -> None:
-        eng = self.eng
-        while self.waiting and None in self.slots:
-            if sum(s is not None for s in self.slots) >= self._cap_eff:
-                break      # capacity shrunk under allocation pressure
-            slot = self.slots.index(None)
-            sess = self.waiting[0]
-            # re-admission after preemption re-prefills the generated
-            # prefix too (the checker already advanced past it)
-            ids = list(sess.prompt_ids) + list(sess.out_ids)
-            reason = self._admission_reject_reason(len(ids))
-            if reason is not None:
-                # unsatisfiable-by-construction: reject NOW (frees the
-                # queue head for admissible requests behind it) instead
-                # of waiting for pages that can never suffice
+        with span("admit"):
+            while self.waiting and None in self.slots:
+                if sum(s is not None for s in self.slots) >= self._cap_eff:
+                    break      # capacity shrunk under allocation pressure
+                slot = self.slots.index(None)
+                sess = self.waiting[0]
+                # re-admission after preemption re-prefills the generated
+                # prefix too (the checker already advanced past it)
+                ids = list(sess.prompt_ids) + list(sess.out_ids)
+                reason = self._admission_reject_reason(len(ids))
+                if reason is not None:
+                    # unsatisfiable-by-construction: reject NOW (frees the
+                    # queue head for admissible requests behind it) instead
+                    # of waiting for pages that can never suffice
+                    self.waiting.popleft()
+                    self._finish(sess, status="rejected", error=reason)
+                    continue
+                page_ids = None
+                cached: List[int] = []
+                if self.paged:
+                    # +1: the first decode write must fit without a new
+                    # allocation, or a lone just-admitted row could preempt
+                    # itself forever without committing a token
+                    n_pg = _ceil_div(len(ids) + 1, self.page_size)
+                    if self._inject("page_exhaustion", sess):
+                        break      # injected dry pool: backpressure path
+                    if self.prefix_cache is not None and not sess.extra_inputs:
+                        # longest shared whole-page prefix, capped one token
+                        # short of the sequence so the boundary page is
+                        # always private (COW write barrier by construction)
+                        cached = self.prefix_cache.lookup(
+                            ids, max_pages=(len(ids) - 1) // self.page_size)
+                    page_ids = self._alloc_pages(n_pg - len(cached))
+                    if page_ids is None:
+                        if cached:
+                            self.pool.release(cached)
+                        break          # backpressure: wait for frees (FIFO)
+                    page_ids = cached + page_ids
                 self.waiting.popleft()
-                self._finish(sess, status="rejected", error=reason)
-                continue
-            page_ids = None
-            cached: List[int] = []
-            if self.paged:
-                # +1: the first decode write must fit without a new
-                # allocation, or a lone just-admitted row could preempt
-                # itself forever without committing a token
-                n_pg = _ceil_div(len(ids) + 1, self.page_size)
-                if self._inject("page_exhaustion", sess):
-                    break      # injected dry pool: backpressure path
-                if self.prefix_cache is not None and not sess.extra_inputs:
-                    # longest shared whole-page prefix, capped one token
-                    # short of the sequence so the boundary page is
-                    # always private (COW write barrier by construction)
-                    cached = self.prefix_cache.lookup(
-                        ids, max_pages=(len(ids) - 1) // self.page_size)
-                page_ids = self._alloc_pages(n_pg - len(cached))
-                if page_ids is None:
+                self._premask.pop(slot, None)
+                self._opp_intervened[slot] = False
+                t0 = time.perf_counter()
+                try:
                     if cached:
-                        self.pool.release(cached)
-                    break          # backpressure: wait for frees (FIFO)
-                page_ids = cached + page_ids
-            self.waiting.popleft()
-            self._premask.pop(slot, None)
-            self._opp_intervened[slot] = False
-            t0 = time.perf_counter()
-            try:
-                if cached:
-                    logits_row = self._cached_prefill(sess, slot, ids,
-                                                      page_ids,
-                                                      len(cached))
-                else:
-                    row_cache = eng.model.init_cache(1, eng.max_len)
-                    inputs = {"tokens": jnp.asarray([ids], jnp.int32)}
-                    if self.bucket_prefill and not eng._needs_refeed \
-                            and not sess.extra_inputs:
-                        # power-of-two bucket: pads ride beyond the valid
-                        # frontier (masked by pos < len, overwritten by
-                        # later decodes), the head reads the true last
-                        # token.  Gated off refeed archs: ring/recurrent
-                        # state would absorb the pads.
-                        p = _bucket_len(len(ids), eng.max_len)
-                        inputs["tokens"] = jnp.asarray(
-                            [ids + [eng.tok.pad_id] * (p - len(ids))],
-                            jnp.int32)
-                        inputs["length"] = jnp.asarray(len(ids), jnp.int32)
-                    if sess.extra_inputs:
-                        inputs.update(sess.extra_inputs)
-                    logits, row_cache = eng._prefill(eng.params, inputs,
-                                                     row_cache)
-                    logits_row = logits[0, -1]
-                    if self.paged:
-                        padded = np.zeros(self.max_pages, np.int32)
-                        padded[:len(page_ids)] = page_ids
-                        self.cache = self._scatter_paged(
-                            self.cache, row_cache, slot,
-                            jnp.asarray(padded))
-                        self._page_tbl[slot, :] = 0
-                        self._page_tbl[slot, :len(page_ids)] = page_ids
-                        self._n_pages_row[slot] = len(page_ids)
-                        self._n_shared_row[slot] = 0
-                        self._pages_dirty = True
+                        logits_row = self._cached_prefill(sess, slot, ids,
+                                                          page_ids,
+                                                          len(cached))
                     else:
-                        self.cache = _scatter_row_donate(self.cache,
-                                                         row_cache, slot)
-            except Exception as e:   # quarantined: reject THIS request
-                if self.paged and page_ids:
-                    self.pool.free(page_ids)
-                self._fail(sess, f"prefill failed: {e!r}")
-                continue
-            if cached:
-                self.n_prefix_hits += 1
-                skipped = len(cached) * self.page_size
-                self.n_prefix_tokens += skipped
-                sess.n_cached_tokens += skipped
-            if self.paged and self.prefix_cache is not None \
-                    and not sess.extra_inputs:
-                # donate the row's full pages right away: requests later
-                # in this same admission sweep (and every future one)
-                # can share the prefix just prefilled
-                n_full = min(len(ids) // self.page_size, len(page_ids))
-                self.prefix_cache.insert(
-                    ids[:n_full * self.page_size], page_ids[:n_full])
-            self._logits = self._logits.at[slot].set(
-                logits_row.astype(jnp.float32))
-            sess.model_time += time.perf_counter() - t0
-            sess.n_fwd += 1
-            self.n_fwd += 1
-            sess.slot = slot
-            sess.t_admit = time.perf_counter()
-            self.slots[slot] = sess
-            # device-table tracking starts (or resumes, after preemption:
-            # the checker already advanced past the generated prefix) at
-            # the checker's CURRENT abstract state
-            self._dev_state[slot] = self._sid_for(sess)
-            self._dev_age[slot] = 0
-            if self.journal is not None:
-                # cache adoption is recorded for observability/auditing;
-                # replay does not need it (restored admissions re-acquire
-                # through the cache or fall back to a full re-prefill,
-                # identical either way by prefix determinism)
-                self.journal.append({"kind": "admit", "rid": sess.rid,
-                                     "slot": slot,
-                                     "cached_pages": len(cached),
-                                     "cached_checker":
-                                         sess.cached_checker})
-            if self._inject("prefill_nan", sess):
-                self._logits = self._logits.at[slot].set(jnp.nan)
+                        logits_row = self._fresh_prefill(sess, slot, ids,
+                                                         page_ids)
+                except Exception as e:   # quarantined: reject THIS request
+                    if self.paged and page_ids:
+                        self.pool.free(page_ids)
+                    self._fail(sess, f"prefill failed: {e!r}")
+                    continue
+                if cached:
+                    self.n_prefix_hits += 1
+                    skipped = len(cached) * self.page_size
+                    self.n_prefix_tokens += skipped
+                    sess.n_cached_tokens += skipped
+                if self.paged and self.prefix_cache is not None \
+                        and not sess.extra_inputs:
+                    # donate the row's full pages right away: requests later
+                    # in this same admission sweep (and every future one)
+                    # can share the prefix just prefilled
+                    n_full = min(len(ids) // self.page_size, len(page_ids))
+                    self.prefix_cache.insert(
+                        ids[:n_full * self.page_size], page_ids[:n_full])
+                self._logits = self._logits.at[slot].set(
+                    logits_row.astype(jnp.float32))
+                sess.model_time += time.perf_counter() - t0
+                sess.n_fwd += 1
+                self.n_fwd += 1
+                sess.slot = slot
+                sess.t_admit = time.perf_counter()
+                self.slots[slot] = sess
+                # device-table tracking starts (or resumes, after preemption:
+                # the checker already advanced past the generated prefix) at
+                # the checker's CURRENT abstract state
+                self._dev_state[slot] = self._sid_for(sess)
+                self._dev_age[slot] = 0
+                if self.journal is not None:
+                    # cache adoption is recorded for observability/auditing;
+                    # replay does not need it (restored admissions re-acquire
+                    # through the cache or fall back to a full re-prefill,
+                    # identical either way by prefix determinism)
+                    self.journal.append({"kind": "admit", "rid": sess.rid,
+                                         "slot": slot,
+                                         "cached_pages": len(cached),
+                                         "cached_checker":
+                                             sess.cached_checker})
+                if self._inject("prefill_nan", sess):
+                    self._logits = self._logits.at[slot].set(jnp.nan)
+
+    def _fresh_prefill(self, sess: Session, slot: int, ids: List[int],
+                       page_ids: Optional[List[int]]):
+        """Admission without a prefix-cache hit: prefill the whole
+        sequence at B=1 and scatter the row cache into ``slot`` (its
+        pages, when paged).  Returns the last real token's logits row.
+        NOT a tick function: runs only from ``_admit``."""
+        eng = self.eng
+        # power-of-two bucket: pads ride beyond the valid frontier (masked
+        # by pos < len, overwritten by later decodes), the head reads the
+        # true last token.  Gated off refeed archs: ring/recurrent state
+        # would absorb the pads.
+        bucket = self.bucket_prefill and not eng._needs_refeed \
+            and not sess.extra_inputs
+        p = _bucket_len(len(ids), eng.max_len) if bucket else len(ids)
+        with span("prefill", rid=sess.rid, tokens=len(ids),
+                              padded=p):
+            row_cache = eng.model.init_cache(1, eng.max_len)
+            inputs = {"tokens": jnp.asarray([ids], jnp.int32)}
+            if bucket:
+                inputs["tokens"] = jnp.asarray(
+                    [ids + [eng.tok.pad_id] * (p - len(ids))], jnp.int32)
+                inputs["length"] = jnp.asarray(len(ids), jnp.int32)
+            if sess.extra_inputs:
+                inputs.update(sess.extra_inputs)
+            logits, row_cache = eng._prefill(eng.params, inputs, row_cache)
+            if self.paged:
+                padded = np.zeros(self.max_pages, np.int32)
+                padded[:len(page_ids)] = page_ids
+                self.cache = self._scatter_paged(
+                    self.cache, row_cache, slot, jnp.asarray(padded))
+                self._page_tbl[slot, :] = 0
+                self._page_tbl[slot, :len(page_ids)] = page_ids
+                self._n_pages_row[slot] = len(page_ids)
+                self._n_shared_row[slot] = 0
+                self._pages_dirty = True
+            else:
+                self.cache = _scatter_row_donate(self.cache, row_cache,
+                                                 slot)
+            return logits[0, -1]
 
     def _cached_prefill(self, sess: Session, slot: int, ids: List[int],
                         page_ids: List[int], n_cached: int):
@@ -917,25 +936,27 @@ class ContinuousBatchingScheduler:
         # per length; pads write garbage above the final frontier (pos >=
         # len is invalid by contract) into private/trash pages only
         p = min(_bucket_len(len(tail), eng.max_len), eng.max_len - start)
-        feed = jnp.asarray(
-            [tail + [eng.tok.pad_id] * (p - len(tail))], jnp.int32)
-        padded = np.zeros(self.max_pages, np.int32)
-        padded[:len(page_ids)] = page_ids
-        view = {
-            "len": jnp.asarray([start], jnp.int32),
-            "head": self.cache["head"],
-            "tail": self.cache["tail"],
-            "group": self.cache["group"],
-            "pages": jnp.asarray(padded)[None, :],
-        }
-        lg, view = eng._decode(eng.params, view, feed)
-        # merge the written pool leaves back; other rows' pages are
-        # untouched (the scatter only wrote this row's private pages)
-        cache = dict(self.cache)
-        cache["head"], cache["tail"] = view["head"], view["tail"]
-        cache["group"] = view["group"]
-        cache["len"] = cache["len"].at[slot].set(len(ids))
-        self.cache = cache
+        with span("prefill", rid=sess.rid, tokens=len(ids),
+                              padded=p):
+            feed = jnp.asarray(
+                [tail + [eng.tok.pad_id] * (p - len(tail))], jnp.int32)
+            padded = np.zeros(self.max_pages, np.int32)
+            padded[:len(page_ids)] = page_ids
+            view = {
+                "len": jnp.asarray([start], jnp.int32),
+                "head": self.cache["head"],
+                "tail": self.cache["tail"],
+                "group": self.cache["group"],
+                "pages": jnp.asarray(padded)[None, :],
+            }
+            lg, view = eng._decode(eng.params, view, feed)
+            # merge the written pool leaves back; other rows' pages are
+            # untouched (the scatter only wrote this row's private pages)
+            cache = dict(self.cache)
+            cache["head"], cache["tail"] = view["head"], view["tail"]
+            cache["group"] = view["group"]
+            cache["len"] = cache["len"].at[slot].set(len(ids))
+            self.cache = cache
         self._page_tbl[slot, :] = 0
         self._page_tbl[slot, :len(page_ids)] = page_ids
         self._n_pages_row[slot] = len(page_ids)
@@ -1397,18 +1418,19 @@ class ContinuousBatchingScheduler:
         ``mask_bits`` API (e.g. test stubs) fall back to packing their
         bool mask."""
         ch = sess.checker
-        if self._inject("mask_delay", sess):
-            time.sleep(self.injector.delay_s)
-        if self._inject("mask_error", sess):
-            raise InjectedFault(
-                f"injected mask-build failure (rid={sess.rid})")
-        before = getattr(ch, "n_mask_memo_hits", 0)
-        t0 = time.perf_counter()
-        if hasattr(ch, "mask_bits"):
-            m = ch.mask_bits()
-        else:
-            m = bitmask.pack_bool(np.asarray(ch.mask()))
-        dt = time.perf_counter() - t0
+        with span("mask", rid=sess.rid):
+            if self._inject("mask_delay", sess):
+                time.sleep(self.injector.delay_s)
+            if self._inject("mask_error", sess):
+                raise InjectedFault(
+                    f"injected mask-build failure (rid={sess.rid})")
+            before = getattr(ch, "n_mask_memo_hits", 0)
+            t0 = time.perf_counter()
+            if hasattr(ch, "mask_bits"):
+                m = ch.mask_bits()
+            else:
+                m = bitmask.pack_bool(np.asarray(ch.mask()))
+            dt = time.perf_counter() - t0
         sess.mask_time += dt
         self.mask_cache_hits += getattr(ch, "n_mask_memo_hits", 0) - before
         return m, dt
@@ -1456,9 +1478,6 @@ class ContinuousBatchingScheduler:
         argmax over the shared packed staging buffer; sampled rows draw
         host-side from their own per-request RNG.  Finishes dead-ended
         sessions; updates intervention stats.  Returns {slot: token}."""
-        eng = self.eng
-        v = eng._v
-
         # one fused readback: per-row raw argmax + per-row finiteness over
         # the real vocab columns (padded columns are legitimately -inf).
         # Guarded: a runtime error HERE is the device being sick, not a
@@ -1467,9 +1486,10 @@ class ContinuousBatchingScheduler:
             raw_dev, fin_dev = self._raw_stats(self._logits)
             return np.asarray(raw_dev), np.asarray(fin_dev)
 
-        ok, got = self.sup.guard(
-            "tick_readback", _readback,
-            inject=lambda: self._inject("device_error"))
+        with span("readback"):
+            ok, got = self.sup.guard(
+                "tick_readback", _readback,
+                inject=lambda: self._inject("device_error"))
         if not ok:
             # every resident recompute-preempts with its validated prefix
             # intact, so outputs are unchanged; selection commits nothing
@@ -1477,8 +1497,16 @@ class ContinuousBatchingScheduler:
             self._engine_reset(f"tick readback failed: {got!r}")
             self.sup.degrade("tick_readback", got)
             return {}
-        raw, finite = got
         self.n_host_syncs += 1         # per-token selection sync point
+        with span("select"):
+            return self._select(*got)
+
+    def _select(self, raw: np.ndarray, finite: np.ndarray
+                ) -> Dict[int, int]:
+        """``_choose`` after its readback: stage each row's packed mask
+        (``raw``: per-row raw argmax, ``finite``: per-row finiteness),
+        then select on the device (greedy) or the host (sampled)."""
+        v = self.eng._v
         masks = self._mask_words              # persistent staging buffer
         self._dev_gather[:] = OFF_FRONTIER
         row_bits: Dict[int, Optional[np.ndarray]] = {}
@@ -1607,74 +1635,82 @@ class ContinuousBatchingScheduler:
         that hit their OWN EOS id or exhaust their own budget.  Returns
         {slot: token} for rows that still need a forward."""
         live: Dict[int, int] = {}
-        for slot, tok in chosen.items():
-            sess = self.slots[slot]
-            if sess is None or sess.slot != slot:
-                continue     # evicted between selection and commit
-            ch = sess.checker
-            # tracked rows select from the TABLE's mask row; a quotient
-            # escape can therefore offer a token the concrete checker
-            # refuses.  advance() leaves state unchanged on False, so the
-            # validated prefix is intact: recompute-preempt the row — it
-            # re-enters through _sid_for's exact entry audit and resumes
-            # on the host path if still escaped.  Untracked rows selected
-            # from the checker's own mask; their advance return keeps the
-            # pre-device-loop (ignore) semantics.
-            tracked = self._dev_state[slot] >= 0
-            try:
-                if tok == sess.eos_id:
+        with span("commit"):
+            for slot, tok in chosen.items():
+                sess = self.slots[slot]
+                if sess is None or sess.slot != slot:
+                    continue     # evicted between selection and commit
+                ch = sess.checker
+                # tracked rows select from the TABLE's mask row; a quotient
+                # escape can therefore offer a token the concrete checker
+                # refuses.  advance() leaves state unchanged on False, so the
+                # validated prefix is intact: recompute-preempt the row — it
+                # re-enters through _sid_for's exact entry audit and resumes
+                # on the host path if still escaped.  Untracked rows selected
+                # from the checker's own mask; their advance return keeps the
+                # pre-device-loop (ignore) semantics.
+                tracked = self._dev_state[slot] >= 0
+                try:
+                    if tok == sess.eos_id:
+                        if ch is not None:
+                            with span("advance", rid=sess.rid):
+                                ok = ch.advance(tok)
+                            if tracked and not ok:
+                                self.n_table_rejects += 1
+                                self._preempt(sess)
+                                continue
+                        sess.finished_eos = True
+                        self._finish(sess)
+                        continue
+                    if ch is not None and sess.speculator is not None \
+                            and hasattr(ch, "clone"):
+                        sess.speculator.observe(ch.state_key(), tok)
                     if ch is not None:
-                        ok = ch.advance(tok)
-                        if tracked and not ok:
-                            self.n_table_rejects += 1
-                            self._preempt(sess)
-                            continue
-                    sess.finished_eos = True
+                        if self._inject("advance_error", sess):
+                            raise InjectedFault(
+                                f"injected advance failure (rid={sess.rid})")
+                        with span("advance", rid=sess.rid):
+                            ok = ch.advance(tok)
+                        self._premask.pop(slot, None)  # state moved: stale
+                        if tracked:
+                            if not ok:
+                                self.n_table_rejects += 1
+                                self._preempt(sess)
+                                continue
+                            self._advance_sid(slot, sess, tok)
+                except Exception as e:   # quarantined: evict THIS row only
+                    self._fail(sess, f"checker failed during advance: {e!r}")
+                    continue
+                sess.out_ids.append(tok)
+                sess.budget -= 1
+                if sess.budget <= 0:
                     self._finish(sess)
                     continue
-                if ch is not None and sess.speculator is not None \
-                        and hasattr(ch, "clone"):
-                    sess.speculator.observe(ch.state_key(), tok)
-                if ch is not None:
-                    if self._inject("advance_error", sess):
-                        raise InjectedFault(
-                            f"injected advance failure (rid={sess.rid})")
-                    ok = ch.advance(tok)
-                    self._premask.pop(slot, None)  # state moved: stale
-                    if tracked:
-                        if not ok:
-                            self.n_table_rejects += 1
-                            self._preempt(sess)
-                            continue
-                        self._advance_sid(slot, sess, tok)
-            except Exception as e:   # quarantined: evict THIS row only
-                self._fail(sess, f"checker failed during advance: {e!r}")
-                continue
-            sess.out_ids.append(tok)
-            sess.budget -= 1
-            if sess.budget <= 0:
-                self._finish(sess)
-                continue
-            live[slot] = tok
+                live[slot] = tok
         return live
 
-    def _run_decode(self, feed: jnp.ndarray,
+    def _run_decode(self, feed: List[List[int]],
                     overlap_fn: Optional[Callable[[], None]] = None):
-        """One batched forward; attributes time/count to resident rows.
-        The forward is dispatched asynchronously; ``overlap_fn`` (next
+        """One batched forward over ``feed`` (a row of token ids per
+        slot), attributing time and count to resident rows.  The
+        forward is dispatched asynchronously; ``overlap_fn`` (next
         step's host-side mask construction) runs while the device
         executes, then we block so per-request model_time_s measures
         execution, not dispatch (the host would otherwise pay the wait
         inside the next tick's argmax readback, attributed to nothing)."""
         eng = self.eng
-        self._sync_pages()
-        t0 = time.perf_counter()
-        lg, self.cache = eng._decode(eng.params, self.cache, feed)
+        with span("dispatch"):
+            self._sync_pages()
+            feed = jnp.asarray(feed, jnp.int32)
+            t0 = time.perf_counter()
+            lg, self.cache = eng._decode(eng.params, self.cache, feed)
         built = []
         if overlap_fn is not None and self.overlap:
-            built = overlap_fn() or []
+            with span("prebuild"):
+                built = overlap_fn() or []
         t_mask_end = time.perf_counter()
-        lg.block_until_ready()
+        with span("wait"):
+            lg.block_until_ready()
         wait = time.perf_counter() - t_mask_end
         # overlap credit only when the device provably outlasted the
         # prebuild (we still had to wait on it afterwards); if the build
@@ -1703,8 +1739,7 @@ class ContinuousBatchingScheduler:
         feed = [[eng.tok.pad_id]] * self.capacity
         for slot, tok in live.items():
             feed[slot] = [tok]
-        lg = self._run_decode(jnp.asarray(feed, jnp.int32),
-                              overlap_fn=self._prebuild_masks)
+        lg = self._run_decode(feed, overlap_fn=self._prebuild_masks)
         self._logits = lg[:, -1].astype(jnp.float32)
         self._inject_nan_rows("decode_nan")
 
@@ -1966,15 +2001,17 @@ class ContinuousBatchingScheduler:
             return           # nothing ran; next tick takes the host path
         t0 = time.perf_counter()
         try:
-            (self.cache, out_lg, state_dev, toks_dev, raws_dev, n_fed_dev,
-             fault_dev, steps_dev) = self._fused_fn(
-                eng.params, self.cache, self._logits,
-                jnp.asarray(state0), jnp.asarray(active0),
-                jnp.asarray(rem0), jnp.asarray(eos0),
-                jnp.asarray(self._nan_plan),
-                self._dts.mask_dev, self._dts.trans_dev,
-                jnp.int32(n_cap))
-            out_lg.block_until_ready()
+            with span("dispatch"):
+                (self.cache, out_lg, state_dev, toks_dev, raws_dev,
+                 n_fed_dev, fault_dev, steps_dev) = self._fused_fn(
+                    eng.params, self.cache, self._logits,
+                    jnp.asarray(state0), jnp.asarray(active0),
+                    jnp.asarray(rem0), jnp.asarray(eos0),
+                    jnp.asarray(self._nan_plan),
+                    self._dts.mask_dev, self._dts.trans_dev,
+                    jnp.int32(n_cap))
+            with span("wait"):
+                out_lg.block_until_ready()
         except Exception as e:
             # an XLA/runtime error escaped the fused dispatch and the
             # donated cache is unrecoverable: reset the engine surface
@@ -2006,12 +2043,13 @@ class ContinuousBatchingScheduler:
                 "fused_block_watchdog",
                 TimeoutError(f"fused block took {dt:.3f}s > watchdog "
                              f"{self.sup.block_watchdog_s:g}s"))
-        toks = np.asarray(toks_dev)
-        raws = np.asarray(raws_dev)
-        state_out = np.asarray(state_dev)
-        n_fed = np.asarray(n_fed_dev)
-        fault = np.asarray(fault_dev)
-        steps_run = int(steps_dev)
+        with span("readback"):
+            toks = np.asarray(toks_dev)
+            raws = np.asarray(raws_dev)
+            state_out = np.asarray(state_dev)
+            n_fed = np.asarray(n_fed_dev)
+            fault = np.asarray(fault_dev)
+            steps_run = int(steps_dev)
         self._last_block_steps = steps_run
         fed_total = int(n_fed.sum())
         if fed_total and dt > 0:
@@ -2022,14 +2060,15 @@ class ContinuousBatchingScheduler:
             self._tok_s_ema = (rate if self._tok_s_ema == 0.0
                                else 0.7 * self._tok_s_ema + 0.3 * rate)
         self.n_fwd += steps_run
-        for slot, sess in enumerate(list(self.slots)):
-            if sess is None:
-                continue
-            sess.n_fwd += int(n_fed[slot])
-            sess.model_time += dt
-            self._resync_row(slot, sess, toks[slot], raws[slot],
-                             bool(fault[slot]), int(state_out[slot]),
-                             steps_run)
+        with span("commit"):
+            for slot, sess in enumerate(list(self.slots)):
+                if sess is None:
+                    continue
+                sess.n_fwd += int(n_fed[slot])
+                sess.model_time += dt
+                self._resync_row(slot, sess, toks[slot], raws[slot],
+                                 bool(fault[slot]), int(state_out[slot]),
+                                 steps_run)
         self._shrink_pages()   # rows that exited early rewound their len
 
     def _resync_row(self, slot: int, sess: Session, toks_row, raws_row,
@@ -2059,7 +2098,9 @@ class ContinuousBatchingScheduler:
             sess.n_int += int(tok != int(raws_row[j]))
             try:
                 if tok == sess.eos_id:
-                    if ch.advance(tok):
+                    with span("advance", rid=sess.rid):
+                        ok = ch.advance(tok)
+                    if ok:
                         sess.finished_eos = True
                         self._finish(sess)
                     else:
@@ -2074,7 +2115,8 @@ class ContinuousBatchingScheduler:
                 if self._inject("advance_error", sess):
                     raise InjectedFault(
                         f"injected advance failure (rid={sess.rid})")
-                ok = ch.advance(tok)
+                with span("advance", rid=sess.rid):
+                    ok = ch.advance(tok)
             except Exception as e:   # quarantined: evict THIS row only
                 self._fail(sess, f"checker failed during advance: {e!r}")
                 return
@@ -2132,8 +2174,7 @@ class ContinuousBatchingScheduler:
             feed = [[pad]] * self.capacity
             for slot, tok in live.items():
                 feed[slot] = [tok]
-            lg = self._run_decode(jnp.asarray(feed, jnp.int32),
-                                  overlap_fn=self._prebuild_masks)
+            lg = self._run_decode(feed, overlap_fn=self._prebuild_masks)
             self._logits = lg[:, -1].astype(jnp.float32)
             self._inject_nan_rows("decode_nan")
             self._shrink_pages()       # return the unused verify window
@@ -2147,23 +2188,24 @@ class ContinuousBatchingScheduler:
         # overlapped prebuild: checker state is post-commit, i.e. exactly
         # the state verification position 0 selects from — _verify_row
         # consumes the mask, and untouched rows keep it for the next tick
-        lg_dev = self._run_decode(jnp.asarray(feed, jnp.int32),
-                                  overlap_fn=self._prebuild_masks)
-        lg_host = np.asarray(lg_dev)[:, :, :eng._v]
+        lg_dev = self._run_decode(feed, overlap_fn=self._prebuild_masks)
+        with span("readback"):
+            lg_host = np.asarray(lg_dev)[:, :, :eng._v]
         # rows not in `live` consumed the full pad width; "accepting" it
         # keeps their (garbage, to-be-overwritten) length bookkeeping
         # consistent with the decoded cache
         accepted_vec = np.full(self.capacity, width - 1, np.int32)
-        for slot, props in proposals.items():
-            try:
-                accepted_vec[slot] = self._verify_row(slot, props,
-                                                      lg_host[slot])
-            except Exception as e:   # quarantined: evict THIS row only
-                accepted_vec[slot] = 0
-                if self.slots[slot] is not None:
-                    self._fail(self.slots[slot],
-                               f"checker failed during speculative "
-                               f"verify: {e!r}")
+        with span("commit"):
+            for slot, props in proposals.items():
+                try:
+                    accepted_vec[slot] = self._verify_row(slot, props,
+                                                          lg_host[slot])
+                except Exception as e:   # quarantined: evict THIS row only
+                    accepted_vec[slot] = 0
+                    if self.slots[slot] is not None:
+                        self._fail(self.slots[slot],
+                                   f"checker failed during speculative "
+                                   f"verify: {e!r}")
         if eng._needs_refeed:
             self._fixup_refeed(snapshot, live, proposals, accepted_vec,
                                lg_dev, width)
@@ -2237,7 +2279,8 @@ class ContinuousBatchingScheduler:
             if self._inject("advance_error", sess):
                 raise InjectedFault(
                     f"injected advance failure (rid={sess.rid})")
-            ch.advance(tok_i)
+            with span("advance", rid=sess.rid):
+                ch.advance(tok_i)
             self._premask.pop(slot, None)   # state moved: mask stale
             if self._dev_state[slot] >= 0:
                 # tok_i was checker-validated above; only the table state
@@ -2284,15 +2327,17 @@ class ContinuousBatchingScheduler:
             idx = jnp.asarray(slots, jnp.int32)
             feed = jnp.asarray([committed[s] for s in slots], jnp.int32)
             t0 = time.perf_counter()
-            rows = _gather_rows_jit(snapshot, idx)
-            lg_re, rows = eng._decode(eng.params, rows, feed)
-            self.cache = _scatter_rows_jit(self.cache, rows, idx)
-            self._logits = self._logits.at[idx].set(
-                lg_re[:, -1].astype(jnp.float32))
+            with span("dispatch"):
+                rows = _gather_rows_jit(snapshot, idx)
+                lg_re, rows = eng._decode(eng.params, rows, feed)
+                self.cache = _scatter_rows_jit(self.cache, rows, idx)
+                self._logits = self._logits.at[idx].set(
+                    lg_re[:, -1].astype(jnp.float32))
             # block so model_time measures execution, not dispatch (the
             # wait would otherwise hide in the next tick's argmax
             # readback, attributed to nothing)
-            lg_re.block_until_ready()
+            with span("wait"):
+                lg_re.block_until_ready()
             dt = time.perf_counter() - t0
             self.n_fwd += 1
             for slot in slots:

@@ -19,8 +19,8 @@ sick device ends back at the fused path and MTTR is measurable.
 
 All timing goes through injectable ``clock``/``sleep`` so tests drive
 watchdogs deterministically; every transition is recorded in
-:attr:`events` and summarized by :meth:`stats` (surfaced in scheduler
-session stats and ``BENCH_serving.json``).
+:attr:`events` and summarized by :meth:`stats` (surfaced in the
+scheduler's ``stats()``).
 """
 from __future__ import annotations
 

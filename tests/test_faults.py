@@ -602,3 +602,35 @@ def test_lint_forbids_swallowed_excepts_in_serving(tmp_path):
     for fn in sorted(os.listdir(pkg)):
         if fn.endswith(".py"):
             assert lint.lint_serving_excepts(os.path.join(pkg, fn)) == []
+
+
+# -- lint: host spans go through tracing.span --------------------------------
+
+
+def test_lint_forbids_profiler_calls_on_the_tick_path(tmp_path):
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sched_path = os.path.join(root, "src", "repro", "serving",
+                              "scheduler.py")
+    spec = importlib.util.spec_from_file_location(
+        "lint_hotpath", os.path.join(root, "tools", "lint_hotpath.py"))
+    lint = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(lint)
+    bad = tmp_path / "sched.py"
+    bad.write_text(
+        "import jax\n"
+        "from jax.profiler import TraceAnnotation\n"
+        "class S:\n"
+        "    def _choose(self):\n"
+        "        with jax.profiler.TraceAnnotation('serve.select'):\n"  # R7
+        "            pick()\n"
+        "        with TraceAnnotation('serve.select'):\n"               # R7
+        "            pick()\n"
+        "        with span('select'):\n"                                 # fine
+        "            pick()\n"
+        "    def _admit(self):\n"                 # not a tick function
+        "        jax.profiler.start_trace('trace-dir')\n")
+    findings = lint.lint_scheduler(str(bad))
+    assert [f.rule for f in findings] == ["R7", "R7"]
+    assert all("_choose()" in f.msg for f in findings)
+    # the scheduler spans its ticks through tracing.span only
+    assert lint.lint_scheduler(sched_path) == []

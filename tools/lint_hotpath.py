@@ -42,7 +42,12 @@ Rules (AST-based, stdlib only):
       tree walk or a hypothesis-set fork per token would put O(prefix)
       host work back on the per-token path the cache exists to shorten.
       Only ``evict()``/``evictable()`` may run from tick functions
-      (``_ensure_pages`` reclaims cache-only pages under pool pressure).
+      (``_ensure_pages`` reclaims cache-only pages under pool pressure);
+  R7  no direct profiler call (``jax.profiler.*``, ``TraceAnnotation``,
+      ``TraceMe``) inside tick-path functions: host spans go through
+      ``span(...)`` (``serving/tracing.py``), which puts each in the
+      ``serve.*`` namespace that trace readers select by — a bare
+      annotation would name a phase no reader looks for.
 
 A finding is suppressed by putting ``# hotpath-lint: allow`` on the
 offending physical line (or the line above it).  Every suppression is a
@@ -87,6 +92,9 @@ ALLOC_FUNCS = {"zeros", "ones", "empty", "full", "tile"}
 PREFIX_CACHE_TICK_OK = {"evict", "evictable"}
 # R5: journal/file-sync entry points banned from tick-path functions
 SYNC_BANNED = {"fsync", "flush", "commit_tick", "sync"}
+# R7: profiler entry points banned from tick-path functions
+PROFILER_MODULES = {"jax.profiler", "profiler"}
+PROFILER_CALLS = {"TraceAnnotation", "TraceMe"}
 CLOCK_BANNED = {("time", "time"), ("datetime", "now"),
                 ("datetime", "utcnow"), ("datetime", "today")}
 RANDOM_FUNCS = {"random", "randint", "choice", "choices", "shuffle",
@@ -172,6 +180,11 @@ def _check_hot_scope(tree_nodes, path: str, lines: List[str],
                 f"at admission/teardown boundaries (_admit/_finish/"
                 f"_preempt/adopt), never on the per-token tick path; "
                 f"only evict()/evictable() may run under pool pressure"))
+        if base in PROFILER_MODULES or name in PROFILER_CALLS:
+            out.append(Finding(
+                path, node.lineno, "R7",
+                f"profiler call {name}(...) in {where} — host spans go "
+                f"through tracing.span(...), which names them serve.*"))
     return out
 
 
