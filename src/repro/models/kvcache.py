@@ -48,6 +48,19 @@ has a live table entry, so readers still only need ``pos < len``; a page
 freed by rollback/eviction may hold stale K/V, but no surviving row's
 table points at it, and its next owner overwrites positions below its own
 frontier before they become visible.
+
+In-place pools (``pool_inplace``): a GQA pool that the Pallas decode
+kernel reads is handed to the decode step whole.  Each layer's new
+tokens are scattered into the stacked pool and the kernel reads it
+through a free reshape (``models/transformer.py``), so no pool is copied,
+sliced or written back.  That needs the pool in the kernel's row-major
+order in HBM.  The TPU lays a pool out by its shape, and for a d_head of
+64 it puts the page axis minor (``{1,4,3,2,0}``) to spare padding D to 128
+lanes; every kernel call would then relayout the pool.  So an in-place
+pool's last axis is d_head rounded up to whole lanes (``pool_width``):
+the chip's own layout is then row-major, with the bytes the kernel's
+lane-padded blocks hold anyway.  Writers zero-pad the new K/V to that
+width; readers see zeros there.
 """
 from __future__ import annotations
 
@@ -57,6 +70,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+
+LANES = 128            # the TPU's vector lanes: a row's minor-axis tile
 
 # block kinds whose cache can take the paged pool layout (everything the
 # paged serving scheduler needs; ring/recurrent/encoder state stays dense)
@@ -73,6 +88,41 @@ def pageable(cfg: ModelConfig) -> bool:
     kinds = list(head) + list(group) + list(tail)
     return (not cfg.is_encoder_decoder
             and all(k in PAGEABLE_KINDS for k in kinds))
+
+
+def pool_inplace(cfg: ModelConfig, kind: str) -> bool:
+    """True iff a paged pool of block ``kind`` is updated in place by the
+    decode step and read whole by the Pallas kernel: GQA attention (incl.
+    a GQA MoE block's), unquantized, with the kernels on.  MLA latent
+    pools, int8 pools and the jnp read keep the per-layer slice."""
+    gqa = kind in ("attn", "shared_attn") or (kind == "moe"
+                                              and cfg.mla is None)
+    return (gqa and cfg.use_pallas_kernels
+            and cfg.kv_cache_dtype != "int8")
+
+
+def pool_width(cfg: ModelConfig) -> int:
+    """Last axis of an in-place pool: d_head in whole lanes."""
+    return -(-cfg.d_head // LANES) * LANES
+
+
+def pad_lanes(x: jnp.ndarray, width: int) -> jnp.ndarray:
+    """``x`` zero-padded along its last axis to ``width``: K/V written to,
+    or a query read against, a pool ``pool_width`` wide."""
+    n = width - x.shape[-1]
+    if n == 0:
+        return x
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, n)])
+
+
+def inplace_layers(cfg: ModelConfig, cache) -> int:
+    """Layers of a decode step over ``cache`` that take the in-place
+    path: every layer of an in-place pool (0 for a dense cache)."""
+    if "pages" not in cache:
+        return 0
+    head, reps, group, tail = cfg.layer_program
+    return (sum(pool_inplace(cfg, k) for k in head + tail)
+            + reps * sum(pool_inplace(cfg, k) for k in group))
 
 
 def block_cache_spec(cfg: ModelConfig, kind: str, batch: int, max_len: int,
@@ -92,9 +142,10 @@ def block_cache_spec(cfg: ModelConfig, kind: str, batch: int, max_len: int,
     lead, t_axis = (n_pages, page_size) if paged else (batch, max_len)
 
     def _kv(t, lead=lead):
+        d = pool_width(cfg) if paged and pool_inplace(cfg, kind) else dh
         spec = {
-            "k": jax.ShapeDtypeStruct((lead, t, nkv, dh), kv_dt),
-            "v": jax.ShapeDtypeStruct((lead, t, nkv, dh), kv_dt),
+            "k": jax.ShapeDtypeStruct((lead, t, nkv, d), kv_dt),
+            "v": jax.ShapeDtypeStruct((lead, t, nkv, d), kv_dt),
         }
         if quant:
             spec["k_scale"] = jax.ShapeDtypeStruct((lead, t, nkv),

@@ -32,6 +32,9 @@ Params = Dict[str, Any]
 @dataclasses.dataclass
 class Model:
     cfg: ModelConfig
+    # layers of the last traced paged decode step that update their pool
+    # in place (kvcache.inplace_layers); set once per trace
+    kv_inplace_layers: int = dataclasses.field(default=0, compare=False)
 
     # -- init -----------------------------------------------------------------
 
@@ -211,6 +214,8 @@ class Model:
             jnp.arange(s, dtype=jnp.int32), (b, s))
         ctx = Ctx(mode="decode", q_pos=pos, cache_len=ln,
                   pages=cache.get("pages"))
+        if ctx.paged:
+            self.kv_inplace_layers = kvcache.inplace_layers(self.cfg, cache)
         x, _, new_cache = stack_apply(params["stack"], self.cfg, x, ctx, cache)
         new_cache["len"] = cache["len"] + s
         return self._head(params, x), new_cache

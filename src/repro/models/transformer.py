@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.models import act_sharding, ssm
+from repro.models import act_sharding, kvcache, ssm
 from repro.models.flash import attention_any
 from repro.models.layers import (attention_init, mla_apply,
                                  mla_apply_absorbed, mla_compress,
@@ -48,6 +48,10 @@ class Ctx:
     # row b lives at pool row pages[b, p // ps], offset p % ps, where ps
     # is the (static) second axis of the pool leaves
     pages: Optional[jnp.ndarray] = None
+    # in-place paged decode (kvcache.pool_inplace): the block's cache is
+    # the whole stacked (reps, n_pages, ps, G, D) pool and this traced
+    # scalar is its layer's index in it
+    layer: Optional[jnp.ndarray] = None
 
     @property
     def ragged(self) -> bool:
@@ -143,14 +147,30 @@ def _self_attention(p: Params, cfg: ModelConfig, xn: jnp.ndarray, ctx: Ctx,
                 # takes the dense read
                 from repro.kernels.decode_attention.ops import \
                     decode_attention
-                out = decode_attention(qg, k_all, v_all, ctx.cache_len + 1,
-                                       block_tables=ctx.pages)
+                tables = ctx.pages
+                if ctx.layer is not None:
+                    # the stacked pool read whole, a free reshape of the
+                    # row-major pool: layer l's page p is row l*n_pages+p.
+                    # Clamp vacant entries BEFORE the offset, or -1 would
+                    # name the previous layer's last page
+                    n_pages = k_all.shape[1]
+                    k_all = k_all.reshape((-1,) + k_all.shape[2:])
+                    v_all = v_all.reshape((-1,) + v_all.shape[2:])
+                    tables = jnp.maximum(ctx.pages, 0) + ctx.layer * n_pages
+                # a lane-padded pool (kvcache.pool_width): zero lanes in q
+                # add nothing to a score, and the output's are dropped
+                qp = kvcache.pad_lanes(qg, k_all.shape[-1])
+                out = decode_attention(qp, k_all, v_all, ctx.cache_len + 1,
+                                       scale=1.0 / (dh ** 0.5),
+                                       block_tables=tables)[..., :dh]
             else:
                 if ctx.paged:
+                    # (a pool laid out for the kernel is read here too,
+                    # as by chip_smoke.py's reference: drop its zero lanes)
                     from repro.kernels.decode_attention.ref import \
                         gather_pages
-                    k_all = gather_pages(k_all, ctx.pages)
-                    v_all = gather_pages(v_all, ctx.pages)
+                    k_all = gather_pages(k_all, ctx.pages)[..., :dh]
+                    v_all = gather_pages(v_all, ctx.pages)[..., :dh]
                 t = k_all.shape[1]
                 k_pos = jnp.broadcast_to(
                     jnp.arange(t, dtype=jnp.int32), (b, t))
@@ -220,14 +240,19 @@ def _write_kv(cache: Params, cfg: ModelConfig, k: jnp.ndarray,
     if ctx.paged and window is None:
         # paged pool: scatter each row's S new tokens through its block
         # table (rows own disjoint pages, so index pairs never collide
-        # across live rows)
-        prow, poff = _page_translate(ctx, b, s, cache["k"].shape[1])
+        # across live rows), into the layer's part of a stacked pool
+        # when the block updates it in place
+        lead = () if ctx.layer is None else (ctx.layer,)
+        prow, poff = _page_translate(ctx, b, s,
+                                     cache["k"].shape[len(lead) + 1])
+        at = lead + (prow, poff)
+        width = cache["k"].shape[-1]            # a lane-padded pool's
         out = dict(cache)
-        out["k"] = cache["k"].at[prow, poff].set(k)
-        out["v"] = cache["v"].at[prow, poff].set(v)
+        out["k"] = cache["k"].at[at].set(kvcache.pad_lanes(k, width))
+        out["v"] = cache["v"].at[at].set(kvcache.pad_lanes(v, width))
         if quant:
-            out["k_scale"] = cache["k_scale"].at[prow, poff].set(k_sc)
-            out["v_scale"] = cache["v_scale"].at[prow, poff].set(v_sc)
+            out["k_scale"] = cache["k_scale"].at[at].set(k_sc)
+            out["v_scale"] = cache["v_scale"].at[at].set(v_sc)
         return out
     if window is None or "pos" not in cache:
         out = dict(cache)
@@ -463,25 +488,34 @@ def stack_apply(params: Params, cfg: ModelConfig, x: jnp.ndarray, ctx: Ctx,
 
     if reps > 0:
         if cache is not None:
-            # The stacked group cache rides in the scan CARRY and is updated
-            # with dynamic_update_index_in_dim — the while-loop in-place
-            # pattern XLA aliases with the donated input buffer (cache in
-            # xs/ys would materialize a second full-size cache).
+            # The stacked group cache rides in the scan CARRY.  An in-place
+            # paged pool goes to its block whole, which scatters the new
+            # tokens at [layer, page, offset] and lets the kernel read the
+            # stacked pool; every other cache is cut out per layer and
+            # written back with dynamic_update_index_in_dim.
             def body(carry, xs):
                 h, aux, gcache = carry
                 p_i, idx = xs
+                gcache = dict(gcache)
                 for j, kind in enumerate(group):
                     pj = shared if kind == "shared_attn" else p_i[f"b{j}"]
-                    cj = jax.tree.map(
-                        lambda a: jax.lax.dynamic_index_in_dim(
-                            a, idx, 0, keepdims=False), gcache[f"b{j}"])
-                    h, a, nc = block_apply(pj, cfg, kind, h, ctx, cj)
+                    key = f"b{j}"
+                    if ctx.paged and kvcache.pool_inplace(cfg, kind):
+                        h, a, gcache[key] = block_apply(
+                            pj, cfg, kind, h,
+                            dataclasses.replace(ctx, layer=idx),
+                            gcache[key])
+                    else:
+                        cj = jax.tree.map(
+                            lambda a: jax.lax.dynamic_index_in_dim(
+                                a, idx, 0, keepdims=False), gcache[key])
+                        h, a, nc = block_apply(pj, cfg, kind, h, ctx, cj)
+                        gcache[key] = jax.tree.map(
+                            lambda full, new:
+                            jax.lax.dynamic_update_index_in_dim(
+                                full, new.astype(full.dtype), idx, 0),
+                            gcache[key], nc)
                     aux = aux + a
-                    gcache = dict(gcache)
-                    gcache[f"b{j}"] = jax.tree.map(
-                        lambda full, new: jax.lax.dynamic_update_index_in_dim(
-                            full, new.astype(full.dtype), idx, 0),
-                        gcache[f"b{j}"], nc)
                 return (h, aux, gcache), None
 
             (x, aux_total, group_cache), _ = jax.lax.scan(

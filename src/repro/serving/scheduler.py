@@ -58,10 +58,12 @@ Design points (ISSUE 1 tentpole):
    at admission (``ceil((prompt+1)/page_size)`` — not a full-length
    stripe), grows rows page-by-page as they decode, shrinks them when
    speculative rollback rewinds the frontier, and frees them the moment
-   a request finishes.  Admission blocks on pool exhaustion (the waiting
-   queue provides backpressure), and mid-flight exhaustion falls back to
-   vLLM-style recompute preemption: the youngest resident row returns
-   its pages and re-enters the queue front, to be re-prefilled
+   a request finishes.  Every program that takes the pool donates it,
+   and the decode step updates it in place (``kvcache.pool_inplace``),
+   so no step copies a pool.  Admission blocks on pool exhaustion (the
+   waiting queue provides backpressure), and mid-flight exhaustion falls
+   back to vLLM-style recompute preemption: the youngest resident row
+   returns its pages and re-enters the queue front, to be re-prefilled
    (prompt + generated prefix) when pages free up — the checker state
    rides along, so outputs are unchanged;
  - speculative decoding (paper §3.6) runs per-row: one (B, 1+s) decode
@@ -249,12 +251,12 @@ def _scatter_row_paged(dst, src, slot, page_ids, page_size: int):
     def p0(d, s):          # head/tail: (P, ps, ...) <- (1, T, ...)
         blk = s[0, :n_pg * page_size].reshape(
             (n_pg, page_size) + s.shape[2:])
-        return d.at[page_ids].set(blk)
+        return d.at[page_ids].set(kvcache.pad_lanes(blk, d.shape[-1]))
 
     def p1(d, s):          # group: (reps, P, ps, ...) <- (reps, 1, T, ...)
         blk = s[:, 0, :n_pg * page_size].reshape(
             (s.shape[0], n_pg, page_size) + s.shape[3:])
-        return d.at[:, page_ids].set(blk)
+        return d.at[:, page_ids].set(kvcache.pad_lanes(blk, d.shape[-1]))
 
     out = dict(dst)
     out["len"] = dst["len"].at[slot].set(src["len"])
@@ -408,6 +410,11 @@ class ContinuousBatchingScheduler:
             self._scatter_paged = jax.jit(
                 functools.partial(_scatter_row_paged, page_size=ps),
                 donate_argnums=(0,))
+            # the batched decode donates the cache, dropped on assignment
+            # (model.decode_step itself: the program stays jit_decode_step);
+            # the engine's own _decode keeps its B=1 caches
+            self._decode = jax.jit(engine.model.decode_step,
+                                   donate_argnums=(1,))
             # per-slot count of block-table entries that map CACHED
             # (shared, read-only) pages — pages [0, n) of the row's
             # table.  The write frontier always sits strictly above the
@@ -417,6 +424,8 @@ class ContinuousBatchingScheduler:
         else:
             self.cache = engine.model.init_cache(self.capacity,
                                                  engine.max_len)
+            # no donation: a spec tick's refeed re-reads its snapshot
+            self._decode = engine._decode
         if prefix_cache and not self.paged:
             raise ValueError("prefix_cache=True requires paged KV "
                              "(pages are the sharing granularity)")
@@ -668,11 +677,18 @@ class ContinuousBatchingScheduler:
             n_prefix_hits=self.n_prefix_hits,
             n_prefix_tokens=self.n_prefix_tokens,
             n_checker_clones=self.n_checker_clones,
+            kv_inplace_layers=self.kv_inplace_layers,
         )
         if self.prefix_cache is not None:
             s.update({"prefix_" + k: v
                       for k, v in self.prefix_cache.stats().items()})
         return s
+
+    @property
+    def kv_inplace_layers(self) -> int:
+        """Layers of the paged decode step that update the pool in place,
+        as its last trace counted (0 for a dense cache)."""
+        return self.eng.model.kv_inplace_layers if self.paged else 0
 
     def step(self) -> List[Session]:
         """One scheduler tick: reap -> admit -> select -> decode.
@@ -949,9 +965,10 @@ class ContinuousBatchingScheduler:
                 "group": self.cache["group"],
                 "pages": jnp.asarray(padded)[None, :],
             }
-            lg, view = eng._decode(eng.params, view, feed)
-            # merge the written pool leaves back; other rows' pages are
-            # untouched (the scatter only wrote this row's private pages)
+            lg, view = self._decode(eng.params, view, feed)
+            # merge the written pool leaves back (the call donated the
+            # batch cache's); other rows' pages are untouched (the
+            # scatter only wrote this row's private pages)
             cache = dict(self.cache)
             cache["head"], cache["tail"] = view["head"], view["tail"]
             cache["group"] = view["group"]
@@ -1136,6 +1153,7 @@ class ContinuousBatchingScheduler:
         finally:
             self._in_reset = False
         eng = self.eng
+        self.cache = None      # two pools at once may not fit the device
         if self.paged:
             self.cache = eng.model.init_cache(
                 self.capacity, eng.max_len, page_size=self.page_size,
@@ -1703,7 +1721,7 @@ class ContinuousBatchingScheduler:
             self._sync_pages()
             feed = jnp.asarray(feed, jnp.int32)
             t0 = time.perf_counter()
-            lg, self.cache = eng._decode(eng.params, self.cache, feed)
+            lg, self.cache = self._decode(eng.params, self.cache, feed)
         built = []
         if overlap_fn is not None and self.overlap:
             with span("prebuild"):
@@ -2183,8 +2201,7 @@ class ContinuousBatchingScheduler:
         for slot, tok in live.items():
             row = [tok] + proposals[slot]
             feed[slot][:len(row)] = row
-        snapshot = self.cache          # JAX arrays are immutable: free
-        snap_len = snapshot["len"]
+        snapshot = self.cache          # for the refeed (dense: not donated)
         # overlapped prebuild: checker state is post-commit, i.e. exactly
         # the state verification position 0 selects from — _verify_row
         # consumes the mask, and untouched rows keep it for the next tick
@@ -2213,9 +2230,11 @@ class ContinuousBatchingScheduler:
             # per-row rollback: KV entries beyond `len` are masked by
             # validity, so rewinding the per-row length is the whole
             # rollback; pages now wholly beyond a frontier go back to the
-            # pool right away
+            # pool right away.  The step advanced every len by `width`
+            # (the paged decode donated the snapshot's)
             cache = dict(self.cache)
-            cache["len"] = snap_len + 1 + jnp.asarray(accepted_vec)
+            cache["len"] = (self.cache["len"] - width + 1
+                            + jnp.asarray(accepted_vec))
             self.cache = cache
             self._logits = lg_dev[
                 jnp.arange(self.capacity), jnp.asarray(accepted_vec)
