@@ -1,8 +1,9 @@
 """The system under test, built from a configuration file and a mix.
 
-One ``ServingEngine`` and one ``ContinuousBatchingScheduler`` serve the
-whole run, warm-up included, so every program the window drives is
-compiled before it starts.  Off: ``debug_invariants``, the journal,
+The configuration's family module (``families/<family>.py``) turns the
+file into the program's model.  One ``ServingEngine`` and one
+``ContinuousBatchingScheduler`` serve the whole run, warm-up included,
+so every program the window drives is compiled before it starts.  Off: ``debug_invariants``, the journal,
 fault injection, grammar analysis and device tables.  On: paged KV, the
 Pallas kernels, and the prefix cache where the configuration says so.
 """
@@ -15,6 +16,7 @@ from typing import Dict, List, Optional
 
 import jax
 
+import families
 from harness import weights
 
 CONFIG_DIR = os.path.join(os.path.dirname(os.path.dirname(
@@ -24,23 +26,6 @@ CONFIG_DIR = os.path.join(os.path.dirname(os.path.dirname(
 def load_config(name: str) -> dict:
     with open(os.path.join(CONFIG_DIR, name + ".json")) as f:
         return json.load(f)
-
-
-def model_config(cfg: dict):
-    from repro.configs.base import ModelConfig
-    m = cfg["model"]
-    if m.get("partial_rotary_factor", 1.0) != 1.0 or m.get("use_qkv_bias"):
-        raise ValueError(f"{cfg['name']}: the dense block rotates the whole "
-                         "head and has no q/k/v biases")
-    return ModelConfig(
-        arch_id=cfg["name"], family="dense",
-        n_layers=m["num_hidden_layers"], d_model=m["hidden_size"],
-        n_heads=m["num_attention_heads"],
-        n_kv_heads=m["num_key_value_heads"], d_ff=m["intermediate_size"],
-        vocab_size=m["vocab_size"], group=("attn",),
-        rope_theta=float(m["rope_theta"]), rms_eps=m["rms_norm_eps"],
-        max_seq_len=m["max_position_embeddings"], dtype=m["torch_dtype"],
-        use_pallas_kernels=True)
 
 
 class Server:
@@ -54,7 +39,7 @@ class Server:
 
         self.cfg = cfg
         self.setup: Dict[str, float] = {}
-        self.model = build_model(model_config(cfg))
+        self.model = build_model(families.of(cfg).model_config(cfg))
         t0 = time.perf_counter()
         self.engine = ServingEngine(self.model, None, tok,
                                     max_len=cfg["server"]["max_len"])

@@ -2,16 +2,22 @@
 
 ``load`` turns the JAX profiler's ``.xplane.pb`` into a ``TraceData``:
 the device operations of each chip (from its ``XLA Ops`` line), the
-executions of each jitted program (``XLA Modules``), and the host spans
-the harness annotated.  The reductions below work on that plain form
-only, so they can be checked on a small synthetic trace:
+executions of each jitted program (``XLA Modules``), the host spans the
+harness annotated (``bench.*``) and the program's own (``serve.*``, one
+per phase of a scheduler tick, ``repro.serving.tracing``).  Host spans
+lie on the clock of the device's operations; the program's nest by
+their intervals, as the scheduler runs on one thread.  The reductions
+below work on that plain form only, so they can be checked on a small
+synthetic trace:
 
 - ``busy_s``: the union of the intervals in which an operation ran on
   the device, averaged over the chips;
 - ``program_calls`` / ``kernel_calls``: executions and device time of a
   program or of the operations whose name matches a kernel's;
-- ``idle_gaps``: stretches with nothing running on the device, each put
-  down to the host span it overlaps most.
+- ``idle_gaps``: the longest stretches with nothing running on the
+  device, each named by the deepest program span that covers most of
+  it, else by the harness span that overlaps it most (``gap_name``);
+- ``inside``: the program spans of one name inside a stretch.
 """
 from __future__ import annotations
 
@@ -32,6 +38,7 @@ class TraceData:
     spans: List[Event]               # host spans the harness annotated
     t_start: int                     # traced window, ns
     t_stop: int
+    program_spans: List[Event] = dataclasses.field(default_factory=list)
 
 
 def _merge(events: Sequence[Event], lo: int, hi: int) -> List[Tuple[int, int]]:
@@ -119,14 +126,11 @@ def top_ops(tr: TraceData, k: int = 10) -> List[List]:
     return [[name, t / n / 1e9] for name, t in tot.most_common(k)]
 
 
-def idle_gaps(tr: TraceData, k: int = 10) -> List[List]:
-    """The ``k`` longest stretches with no device operation on the first
-    chip, each named by the host span that overlaps it most (``idle``
-    where none does)."""
+def device_gaps(tr: TraceData) -> List[Tuple[int, int]]:
+    """Stretches of the window with no operation on the first chip."""
     if not tr.ops:
         return []
-    chip = sorted(tr.ops)[0]
-    busy = _merge(tr.ops[chip], tr.t_start, tr.t_stop)
+    busy = _merge(tr.ops[sorted(tr.ops)[0]], tr.t_start, tr.t_stop)
     gaps, cur = [], tr.t_start
     for s, e in busy:
         if s > cur:
@@ -134,16 +138,51 @@ def idle_gaps(tr: TraceData, k: int = 10) -> List[List]:
         cur = max(cur, e)
     if tr.t_stop > cur:
         gaps.append((cur, tr.t_stop))
-    gaps.sort(key=lambda g: g[0] - g[1])
-    out = []
-    for s, e in gaps[:k]:
-        best, over = "idle", 0
-        for name, hs, he in tr.spans:
-            o = min(e, he) - max(s, hs)
-            if o > over:
-                best, over = name, o
-        out.append([best, (e - s) / 1e9])
-    return out
+    return gaps
+
+
+def _overlap(s: int, e: int, span: Event) -> int:
+    return min(e, span[2]) - max(s, span[1])
+
+
+def _length(span: Event) -> int:
+    return span[2] - span[1]
+
+
+def gap_name(s: int, e: int, program: Sequence[Event],
+             harness: Sequence[Event]) -> str:
+    """The deepest program span covering more than half of [s, e); else
+    the program span overlapping it most (the deeper on a tie); else the
+    harness span overlapping it most; else ``idle``."""
+    touching = [(o, p) for p in program if (o := _overlap(s, e, p)) > 0]
+    if touching:
+        # spans nest, so the deepest of those around a stretch is the
+        # shortest
+        most = [p for o, p in touching if 2 * o > e - s]
+        if not most:
+            top = max(o for o, _ in touching)
+            most = [p for o, p in touching if o == top]
+        return min(most, key=_length)[0]
+    best, over = "idle", 0
+    for h in harness:
+        o = _overlap(s, e, h)
+        if o > over:
+            best, over = h[0], o
+    return best
+
+
+def idle_gaps(tr: TraceData, k: int = 10) -> List[List]:
+    """The ``k`` longest device gaps as ``[name, seconds]``, each named
+    by ``gap_name``."""
+    gaps = sorted(device_gaps(tr), key=lambda g: g[0] - g[1])[:k]
+    return [[gap_name(s, e, tr.program_spans, tr.spans), (e - s) / 1e9]
+            for s, e in gaps]
+
+
+def inside(tr: TraceData, name: str, lo: int, hi: int) -> List[Event]:
+    """The program spans called ``name`` that lie inside [lo, hi]."""
+    return [p for p in tr.program_spans
+            if p[0] == name and lo <= p[1] and p[2] <= hi]
 
 
 # -- reading the profiler's file --------------------------------------------
@@ -151,6 +190,7 @@ def idle_gaps(tr: TraceData, k: int = 10) -> List[List]:
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 HOST_PLANE = "/host:CPU"
 SPAN_PREFIX = "bench."
+PROGRAM_PREFIX = "serve."
 
 
 def find_xplane(trace_dir: str) -> str:
@@ -172,6 +212,7 @@ def load(path: str, t_start_host: float, t_stop_host: float,
     ops: Dict[str, List[Event]] = {}
     modules: Dict[str, List[Event]] = {}
     spans: List[Event] = []
+    program: List[Event] = []
     for plane in pd.planes:
         if DEVICE_PLANE.match(plane.name):
             for line in plane.lines:
@@ -183,13 +224,18 @@ def load(path: str, t_start_host: float, t_stop_host: float,
                     modules[plane.name] = evs
         elif plane.name == HOST_PLANE:
             for line in plane.lines:
-                spans += [(e.name, int(e.start_ns), int(e.end_ns))
-                          for e in line.events
-                          if e.name.startswith(SPAN_PREFIX)]
+                for e in line.events:
+                    ev = (e.name, int(e.start_ns), int(e.end_ns))
+                    if e.name.startswith(SPAN_PREFIX):
+                        spans.append(ev)
+                    elif e.name.startswith(PROGRAM_PREFIX):
+                        program.append(ev)
     if not spans:
         raise ValueError("the trace holds no bench.* host span")
     first = min(s for _, s, _ in spans)
     off = first - int(anchor_host * 1e9)
     return TraceData(ops=ops, modules=modules, spans=spans,
                      t_start=int(t_start_host * 1e9) + off,
-                     t_stop=int(t_stop_host * 1e9) + off)
+                     t_stop=int(t_stop_host * 1e9) + off,
+                     program_spans=sorted(program,
+                                          key=lambda e: (e[1], -e[2])))

@@ -6,9 +6,13 @@ a hash of the leaf's name, and a stacked leaf layer by layer from
 uniformly from [-2^15, 2^15), times a power of two, rounded to the
 served dtype: integer draws and an exact scaling, so the program's
 weights and the reference's copy of one layer are bit for bit the same
-wherever either is made.  The spread is the program's own init
-(``1/sqrt(fan_in)``, 1 for the embedding), as a uniform distribution;
-norm scales are ones.
+wherever either is made.  The spread is ``1/sqrt(fan_in)`` (1 for the
+embedding), as a uniform distribution; norm scales are ones.  Fan-in is
+the contraction size of one layer's leaf: ``shape[-2]`` for a leaf of
+two or more axes, so an expert leaf ``(E, d, f)`` gets ``1/sqrt(d)``,
+and ``shape[0]`` for a 1-D leaf.  For a 2-D leaf that is the program's
+own init (``dense_init`` in ``repro.models.layers``); for a 3-D leaf
+that init takes ``shape[0]``, the number of experts.
 """
 from __future__ import annotations
 
@@ -37,7 +41,8 @@ def leaf_key(key: jax.Array, name: str) -> jax.Array:
 
 def _scale(name: str, shape: Tuple[int, ...]) -> float:
     """The power of two that gives the draw the init's spread."""
-    std = 1.0 if name == "embed" else 1.0 / math.sqrt(shape[0])
+    fan_in = shape[-2] if len(shape) >= 2 else shape[0]
+    std = 1.0 if name == "embed" else 1.0 / math.sqrt(fan_in)
     return 2.0 ** round(math.log2(std * math.sqrt(3.0) / HALF))
 
 

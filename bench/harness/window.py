@@ -11,6 +11,10 @@ After the window closes the traffic keeps coming, so the load does not
 change, until every request that fell due inside the window has
 finished, or ``DRAIN_CAP_S`` has passed; requests still open then count
 as failed.
+
+The scheduler's counters (the numeric entries of its ``stats()``) are
+read when the window opens and when it closes, so a reader of a
+cumulative count can take their difference.
 """
 from __future__ import annotations
 
@@ -43,6 +47,9 @@ class Window:
     compiled: List[str]               # programs lowered in the window
     lateness_s: List[float]           # submit - due, per request
     traced: Optional[dict] = None     # what the traced slice recorded
+    counters_open: Dict[str, float] = dataclasses.field(default_factory=dict)
+    counters_close: Dict[str, float] = dataclasses.field(
+        default_factory=dict)
 
     def in_window(self) -> List[Record]:
         return [r for r in self.records if self.t0 <= r.t_due < self.t_end]
@@ -82,6 +89,12 @@ def _span(name: str, on: bool):
             else contextlib.nullcontext())
 
 
+def counters(sched) -> Dict[str, float]:
+    """The numeric entries of the scheduler's ``stats()``."""
+    return {k: v for k, v in sched.stats().items()
+            if isinstance(v, (int, float)) and not isinstance(v, bool)}
+
+
 def submit(server, turn):
     """Queue one greedy turn, under DOMINO with unbounded lookahead when
     it names a grammar."""
@@ -103,6 +116,8 @@ def run(server, gen, seconds: float, counter: CompileCounter,
     live: Dict[int, Record] = {}
     records: List[Record] = []
     lateness: List[float] = []
+    opened = counters(sched)
+    closed = None
     t0 = time.perf_counter()
     t_end = t0 + seconds
     gen.start(t0)
@@ -126,6 +141,7 @@ def run(server, gen, seconds: float, counter: CompileCounter,
         if now >= t_end:
             if n_window is None:
                 n_window = counter.names[n_compiles0:]
+                closed = counters(sched)
             open_ = [r for r in records
                      if r.t_done is None and r.t_due < t_end]
             if not open_ or now >= t_end + DRAIN_CAP_S:
@@ -171,4 +187,5 @@ def run(server, gen, seconds: float, counter: CompileCounter,
         jax.profiler.stop_trace()
     return Window(t0=t0, t_end=t_end, records=records,
                   compiled=n_window or [], lateness_s=lateness,
-                  traced=traced)
+                  traced=traced, counters_open=opened,
+                  counters_close=closed)

@@ -1,10 +1,11 @@
 """Kernels: the Pallas decode-attention kernel's share of its roofline.
 Per call (one layer of one step), the least time is the larger of its
-FLOPs over the bf16 peak and its logical K/V, query and output bytes
-over HBM bandwidth (``harness.flops.decode_attention``; memory bounds
-it); the share is that over the kernel's mean device time per call."""
+FLOPs over the bf16 peak and its logical bytes over HBM bandwidth, as
+the configuration's family counts them (``attention_cost``); the share
+is that over the kernel's mean device time per call."""
+import families
 from harness import flops, trace
-from metrics._steps import dims, share, steps
+from metrics._steps import share, steps
 
 KERNEL = r"^%decode_attention_pallas"
 
@@ -16,7 +17,7 @@ def read(run):
     n, t = trace.kernel_calls(run.trace, KERNEL)
     if not n:
         return None
-    _, _, nq, nkv, dh, _ = dims(run)
-    mins = [flops.min_time(*flops.decode_attention(k, nq, nkv, dh),
-                           run.peaks)[0] for k in st]
+    cfg = run.cell.config
+    cost = families.of(cfg).attention_cost
+    mins = [flops.min_time(*cost(cfg, k), run.peaks)[0] for k in st]
     return share(sum(mins) / len(mins), t / n)

@@ -1,8 +1,9 @@
-"""Model step: the decode step's algorithmic FLOPs (weights and head
-for each resident row, attention over its keys; ``harness.flops``)
-over its device time times the chip's bf16 peak."""
-from harness import flops, trace
-from metrics._steps import DECODE_PROGRAM, dims, share, steps
+"""Model step: the decode step's algorithmic FLOPs over the resident
+rows, as the configuration's family counts them (``step_flops``), over
+its device time times the chip's bf16 peak."""
+import families
+from harness import trace
+from metrics._steps import DECODE_PROGRAM, share, steps
 
 
 def read(run):
@@ -12,8 +13,7 @@ def read(run):
     n, t = trace.program_calls(run.trace, DECODE_PROGRAM)
     if not n:
         return None
-    L, d, nq, nkv, dh, f = dims(run)
-    vocab = run.cell.config["model"]["vocab_size"]
-    per_call = sum(flops.decode_step(k, L, d, nq, nkv, dh, f, vocab)
-                   for k in st) / len(st)
+    cfg = run.cell.config
+    step_flops = families.of(cfg).step_flops
+    per_call = sum(step_flops(cfg, k) for k in st) / len(st)
     return share(per_call * n / run.peaks["bf16_flops_per_s"], t)

@@ -4,8 +4,11 @@
   python3 bench/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
 Everything is found by name from ``BENCHMARK.json``: the workload's
-configuration in ``bench/configs/<config>.json``, its traffic mix in
-``bench/traffic/<mix>.json``, the limits of its check in
+configuration in ``bench/configs/<config>.json``, whose ``family`` names
+the module that makes the model and counts its FLOPs,
+``bench/families/<family>.py``, and its plain reference,
+``bench/reference/<family>.py``; its traffic
+mix in ``bench/traffic/<mix>.json``, the limits of its check in
 ``bench/checks/<workload>.json``, and each per-layer metric's reader in
 ``bench/metrics/<metric>.py``.
 
@@ -22,7 +25,9 @@ what was served (``harness/check.py``).
 The last line of standard output is the result; the last lines of
 standard error are the numbers compared, each beside its limit.  Exits
 non-zero, printing no result, when JAX finds no TPU or fewer chips than
-the cell asks for.
+the cell asks for, and when no result is printed ``DEADLINE_S`` after
+the process started: then the last line of standard error names the
+phase the run was in.
 """
 import time
 
@@ -37,6 +42,7 @@ import os  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
+import threading  # noqa: E402
 from concurrent.futures import ThreadPoolExecutor  # noqa: E402
 from typing import Dict, List, Optional  # noqa: E402
 
@@ -50,10 +56,40 @@ MIN_CHECK_TOKENS = 384       # served tokens the reference compares, at least
 TRACE_START_FRAC = 0.4       # the traced slice starts this far in ...
 TRACE_MAX_S = 8.0            # ... and lasts at most this long
 WARM_THREADS = 8             # compiles the warm-up runs side by side
+DEADLINE_S = 900.0           # no result by then: fail, naming the phase
+DEADLINE_RC = 4
 
 
 def log(msg: str) -> None:
     print(msg, file=sys.stderr, flush=True)
+
+
+class Deadline:
+    """The phase a run is in, and, once started, a timer that ends the
+    process ``DEADLINE_S`` after it started if no result has been
+    printed by then, naming that phase on standard error."""
+
+    def __init__(self):
+        self.phase = "set-up"
+        self._timer: Optional[threading.Timer] = None
+
+    def enter(self, phase: str) -> None:
+        self.phase = phase
+
+    def start(self) -> None:
+        left = DEADLINE_S - (time.perf_counter() - T_START)
+        self._timer = threading.Timer(max(0.0, left), self._expire)
+        self._timer.daemon = True
+        self._timer.start()
+
+    def cancel(self) -> None:
+        if self._timer is not None:
+            self._timer.cancel()
+
+    def _expire(self) -> None:
+        log(f"error: deadline of {DEADLINE_S:g} s after process start "
+                 f"passed in {self.phase}")
+        os._exit(DEADLINE_RC)
 
 
 @dataclasses.dataclass
@@ -101,6 +137,8 @@ class RunData:
     trace: Optional[object]           # harness.trace.TraceData
     peaks: Dict[str, float]
     n_vocab: int                      # the tokenizer's ids
+    counters_open: Dict[str, float]   # the scheduler's, as the window
+    counters_close: Dict[str, float]  # opens and as it closes
 
 
 def end_to_end(run: RunData, setup_s: float) -> Dict[str, float]:
@@ -190,7 +228,7 @@ def warm(srv, gen, cell: Cell) -> int:
     return n + len(lengths)
 
 
-def prepare(cell: Cell, seed: int):
+def prepare(cell: Cell, seed: int, deadline: Optional[Deadline] = None):
     """Everything set-up does, for ``seed``: (server, generator, compile
     counter)."""
     from harness import server, tokenizer, traffic, window
@@ -199,6 +237,8 @@ def prepare(cell: Cell, seed: int):
     gen = traffic.Generator(cell.mix, seed, tok)
     srv = server.Server(cell.config, cell.mix["grammars"], tok, seed,
                         gen.pinned_prompts())
+    if deadline is not None:
+        deadline.enter("warm-up")
     t0 = time.perf_counter()
     n_warm = warm(srv, gen, cell)
     srv.setup["warm_s"] = time.perf_counter() - t0
@@ -218,7 +258,7 @@ def verify(cell: Cell, recs, seed: int, vocab, eos: int,
     control under ``"controls"``."""
     from harness import check
     picked = check.sample(recs, seed, MIN_CHECK_TOKENS)
-    ref = importlib.import_module("reference." + cell.config["reference"])
+    ref = importlib.import_module("reference." + cell.config["family"])
     res = (check.compare(ref, cell.config, seed, picked, vocab, eos,
                          controls) if picked else
            {"positions": 0, "requests": 0,
@@ -230,10 +270,12 @@ def verify(cell: Cell, recs, seed: int, vocab, eos: int,
 
 
 def execute(cell: Cell, seed: int, seconds: float, trace: bool,
-            require_tpu: bool = True, log=log):
+            require_tpu: bool = True, log=log,
+            deadline: Optional[Deadline] = None):
     """One run of ``cell``.  Returns (exit code, result or None)."""
     import jax
 
+    deadline = deadline or Deadline()
     devs = jax.devices()
     plat = devs[0].platform
     if require_tpu and (plat != "tpu" or len(devs) < cell.chips):
@@ -245,7 +287,7 @@ def execute(cell: Cell, seed: int, seconds: float, trace: bool,
     kind = devs[0].device_kind
     peaks = flops.peaks(kind) if require_tpu else {}
     log(f"device: platform {plat}, kind {kind}, count {len(devs)}")
-    srv, gen, counter = prepare(cell, seed)
+    srv, gen, counter = prepare(cell, seed, deadline)
     setup_s = time.perf_counter() - T_START
     log(f"set-up {setup_s:.2f} s: " + ", ".join(
         f"{k} {v:.2f}" for k, v in srv.setup.items())
@@ -257,6 +299,7 @@ def execute(cell: Cell, seed: int, seconds: float, trace: bool,
                    "start_s": TRACE_START_FRAC * seconds,
                    "length_s": min(TRACE_MAX_S,
                                    (1 - TRACE_START_FRAC) * seconds)}
+    deadline.enter("window")
     win = window.run(srv, gen, seconds, counter, tr_spec)
     stats = devs[0].memory_stats() or {}
     mem_peak = int(stats.get("peak_bytes_in_use", 0))
@@ -270,13 +313,16 @@ def execute(cell: Cell, seed: int, seconds: float, trace: bool,
         f"{max(win.lateness_s, default=0):.4f} s")
     tr = None
     if tr_spec is not None:
+        deadline.enter("trace")
         if win.traced is not None and require_tpu:
             tr = tracemod.load(tracemod.find_xplane(tr_spec["dir"]),
                                win.traced["t_start"], win.traced["t_stop"],
                                win.traced["anchor"])
         shutil.rmtree(tr_spec["dir"], ignore_errors=True)
     run = RunData(cell=cell, window=win, trace=tr, peaks=peaks,
-                  n_vocab=srv.engine.tok.vocab_size)
+                  n_vocab=srv.engine.tok.vocab_size,
+                  counters_open=win.counters_open,
+                  counters_close=win.counters_close)
     metrics = per_layer(run) if trace else end_to_end(run, setup_s)
     units = cell.per_layer if trace else cell.end_to_end
     # the reference runs on a chip the program no longer holds
@@ -284,6 +330,7 @@ def execute(cell: Cell, seed: int, seconds: float, trace: bool,
     srv.close()
     gc.unfreeze()
     gc.collect()
+    deadline.enter("reference check")
     t0 = time.perf_counter()
     res = verify(cell, recs, seed, list(tok.vocab), tok.eos_id)
     log(f"reference over {res['requests']} requests, {res['positions']} "
@@ -327,11 +374,15 @@ def main(argv=None) -> int:
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     args = ap.parse_args(argv)
+    deadline = Deadline()
+    deadline.start()
     configure_cache()
     cell = load_cell(args.workload)
-    rc, result = execute(cell, args.seed, args.seconds, bool(args.trace))
+    rc, result = execute(cell, args.seed, args.seconds, bool(args.trace),
+                         deadline=deadline)
     if result is not None:
         print(json.dumps(result), flush=True)
+    deadline.cancel()
     return rc
 
 

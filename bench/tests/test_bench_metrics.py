@@ -37,7 +37,11 @@ def _run(with_trace=True):
         in_window=lambda: [rec])
     return run.RunData(cell=cell, window=win,
                        trace=tr if with_trace else None, peaks=PEAK,
-                       n_vocab=16387)
+                       n_vocab=16387,
+                       counters_open={"kv_inplace_layers": 24,
+                                      "n_prefix_hits": 3},
+                       counters_close={"kv_inplace_layers": 24,
+                                       "n_prefix_hits": 9})
 
 
 def read(name, r):
@@ -75,9 +79,28 @@ def test_idle_queue_checker():
     assert read("checker_crit_us_per_tok", r) == pytest.approx(30.0)
 
 
+def test_values_as_before():
+    """What each trace reader read on this run before the FLOP and byte
+    counts moved into the family modules, to the last digit."""
+    r = _run()
+    assert read("decode_step_ms", r) == 30.0
+    assert read("decode_mfu", r) == 0.09870431296108291
+    assert read("decode_attention_roofline", r) == 2.0154920634920637
+    assert read("masked_argmax_roofline", r) == 2.7516483516483516
+    assert read("device_idle_share", r) == 90.4
+
+
+def test_counter_at_close():
+    assert read("kv_inplace_layers", _run()) == 24
+    r = _run()
+    r.counters_close = {}
+    assert read("kv_inplace_layers", r) is None
+
+
 @pytest.mark.parametrize("name", ["decode_step_ms", "decode_mfu",
                                   "decode_attention_roofline",
                                   "masked_argmax_roofline",
-                                  "device_idle_share"])
+                                  "device_idle_share", "advance_us_per_tok",
+                                  "tick_host_ms"])
 def test_silent_without_trace(name):
     assert read(name, _run(with_trace=False)) is None
