@@ -5,6 +5,7 @@
   loss(params, batch)                -> (scalar, metrics)
   prefill(params, inputs, cache)     -> (last logits, cache)
   decode_step(params, cache, tokens) -> (logits (B,S_new,V), cache)
+  prefill_window(params, cache, tokens, length) -> (last logits, cache)
 
 ``tokens`` in decode_step may carry S_new > 1 — that is the speculative
 verification path of the paper (§3.6): one forward pass scores all
@@ -206,6 +207,28 @@ class Model:
                     tokens: jnp.ndarray) -> Tuple[jnp.ndarray, Any]:
         """tokens: (B, S_new).  S_new=1 for plain decode; >1 verifies a
         speculative chain in one pass.  Returns logits (B,S_new,V)."""
+        x, new_cache = self._decode_stack(params, cache, tokens)
+        new_cache["len"] = cache["len"] + tokens.shape[1]
+        return self._head(params, x), new_cache
+
+    def prefill_window(self, params: Params, cache, tokens: jnp.ndarray,
+                       length: jnp.ndarray) -> Tuple[jnp.ndarray, Any]:
+        """Prefill the tail of a prompt whose prefix the cache already
+        holds (a prefix-cache hit) as one decode window: ``tokens``
+        (B, S) holds ``length`` real tokens, right-padded to a bucket
+        size.  Each row's keys from ``cache['len']`` on are written, pads
+        beyond the new frontier (garbage by contract), ``len`` advances
+        by ``length``, and the head is read at the last real token only.
+        Returns (logits (B, V), cache)."""
+        x, new_cache = self._decode_stack(params, cache, tokens)
+        new_cache["len"] = cache["len"] + length
+        last = jax.lax.dynamic_slice_in_dim(x, length - 1, 1, axis=1)
+        return self._head(params, last)[:, 0], new_cache
+
+    def _decode_stack(self, params: Params, cache, tokens: jnp.ndarray):
+        """The decoder stack over ``tokens`` (B, S) placed at each row's
+        ``cache['len']``: (hidden states (B, S, D), cache with their
+        keys written)."""
         x = self._embed(params, tokens)
         b, s, _ = x.shape
         ln = cache["len"]
@@ -217,8 +240,7 @@ class Model:
         if ctx.paged:
             self.kv_inplace_layers = kvcache.inplace_layers(self.cfg, cache)
         x, _, new_cache = stack_apply(params["stack"], self.cfg, x, ctx, cache)
-        new_cache["len"] = cache["len"] + s
-        return self._head(params, x), new_cache
+        return x, new_cache
 
     def rollback(self, cache, n_tokens: int):
         """Speculative rollback: rewind ``len`` (KV entries beyond len are

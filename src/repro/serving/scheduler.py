@@ -315,12 +315,29 @@ _scatter_rows_jit = jax.jit(_scatter_rows)
 _gather_rows_jit = jax.jit(_gather_rows)
 
 
-def _bucket_len(n: int, cap: int) -> int:
-    """Smallest power of two >= n, clamped to the cache capacity."""
+def _bucket_len(n: int, cap: int, floor: int = 1) -> int:
+    """Smallest power of two >= max(n, floor), clamped to ``cap``.
+
+    A prefix-cache hit's tail takes one page as ``floor`` and ``max_len``
+    as ``cap``, not ``max_len - start``: a cap for each start would give
+    every start near the end of the cache a program of its own, lowered
+    while serving, and pads past ``max_len`` are written to the trash
+    page (``transformer._page_translate``)."""
     p = 1
-    while p < n:
+    while p < max(n, floor):
         p *= 2
     return min(p, cap)
+
+
+def _window_buckets(floor: int, longest: int, cap: int) -> List[int]:
+    """Every padded length ``_bucket_len`` gives a hit's tail of 1 to
+    ``longest`` tokens."""
+    out, n = [], 1
+    while True:
+        out.append(_bucket_len(n, cap, floor))
+        if out[-1] >= min(longest, cap):
+            return sorted(set(out))
+        n = out[-1] + 1
 
 
 def _ceil_div(a: int, b: int) -> int:
@@ -415,6 +432,10 @@ class ContinuousBatchingScheduler:
             # the engine's own _decode keeps its B=1 caches
             self._decode = jax.jit(engine.model.decode_step,
                                    donate_argnums=(1,))
+            # a prefix-cache hit's tail: one window over a B=1 view of the
+            # donated pool, a program of its own (jit_prefill_window)
+            self._prefill_window = jax.jit(engine.model.prefill_window,
+                                           donate_argnums=(1,))
             # per-slot count of block-table entries that map CACHED
             # (shared, read-only) pages — pages [0, n) of the row's
             # table.  The write frontier always sits strictly above the
@@ -433,6 +454,8 @@ class ContinuousBatchingScheduler:
                              if prefix_cache else None)
         self.n_prefix_hits = 0         # admissions served >= 1 cached page
         self.n_prefix_tokens = 0       # prefill tokens skipped via cache
+        self.n_prompt_tokens = 0       # prompt tokens admitted
+        self.n_prefill_windows = 0     # hit tails prefilled as a window
         self.n_checker_clones = 0      # adopt() replays served by snapshot
         self._in_reset = False         # engine reset in flight: cached
         #                                pages are garbage, don't insert
@@ -603,7 +626,7 @@ class ContinuousBatchingScheduler:
                 break
             row_cache = eng.model.init_cache(1, eng.max_len)
             _, row_cache = eng._prefill(
-                eng.params, {"tokens": jnp.asarray([cut], jnp.int32)},
+                eng.params, {"tokens": jnp.asarray(np.asarray([cut], np.int32))},
                 row_cache)
             padded = np.zeros(self.max_pages, np.int32)
             padded[:n_full] = pages
@@ -617,6 +640,24 @@ class ContinuousBatchingScheduler:
             self.cache = cache
             self.prefix_cache.insert(cut, pages, pin=True)
             self.pool.release(pages)   # ownership passes to the nodes
+            self._warm_hits(pages)
+
+    def _warm_hits(self, shared: List[int]) -> None:
+        """Compile every hit program a prompt sharing the ``shared``
+        pages can reach: one window per tail bucket, each of one real
+        token into a private page that is freed afterwards (its pads
+        land beyond the frontier, in that page or the trash page)."""
+        start = len(shared) * self.page_size
+        page = self._alloc_pages(1)
+        if page is None:
+            return
+        table = np.zeros((1, self.max_pages), np.int32)
+        table[0, :len(shared) + 1] = list(shared) + page
+        for p in _window_buckets(self.page_size, self.eng.max_len - 1 - start,
+                                 self.eng.max_len):
+            _, self.cache = self._run_window(start, table, [0], p)
+        jax.block_until_ready(self.cache)
+        self.pool.free(page)
 
     def submit(self, request: Union[str, Request],
                extra_inputs=None) -> Session:
@@ -676,6 +717,8 @@ class ContinuousBatchingScheduler:
                            else self.journal.n_syncs),
             n_prefix_hits=self.n_prefix_hits,
             n_prefix_tokens=self.n_prefix_tokens,
+            n_prompt_tokens=self.n_prompt_tokens,
+            n_prefill_windows=self.n_prefill_windows,
             n_checker_clones=self.n_checker_clones,
             kv_inplace_layers=self.kv_inplace_layers,
         )
@@ -850,11 +893,14 @@ class ContinuousBatchingScheduler:
                         self.pool.free(page_ids)
                     self._fail(sess, f"prefill failed: {e!r}")
                     continue
+                self.n_prompt_tokens += len(ids)
+                sess.hit_window = None
                 if cached:
                     self.n_prefix_hits += 1
                     skipped = len(cached) * self.page_size
                     self.n_prefix_tokens += skipped
                     sess.n_cached_tokens += skipped
+                    sess.hit_window = (skipped, len(ids) - skipped)
                 if self.paged and self.prefix_cache is not None \
                         and not sess.extra_inputs:
                     # donate the row's full pages right away: requests later
@@ -906,11 +952,13 @@ class ContinuousBatchingScheduler:
         with span("prefill", rid=sess.rid, tokens=len(ids),
                               padded=p):
             row_cache = eng.model.init_cache(1, eng.max_len)
-            inputs = {"tokens": jnp.asarray([ids], jnp.int32)}
+            # numpy first: a list would lower a conversion program per
+            # prompt length
+            feed = np.full((1, p), eng.tok.pad_id, np.int32)
+            feed[0, :len(ids)] = ids
+            inputs = {"tokens": jnp.asarray(feed)}
             if bucket:
-                inputs["tokens"] = jnp.asarray(
-                    [ids + [eng.tok.pad_id] * (p - len(ids))], jnp.int32)
-                inputs["length"] = jnp.asarray(len(ids), jnp.int32)
+                inputs["length"] = jnp.asarray(np.int32(len(ids)))
             if sess.extra_inputs:
                 inputs.update(sess.extra_inputs)
             logits, row_cache = eng._prefill(eng.params, inputs, row_cache)
@@ -934,52 +982,64 @@ class ContinuousBatchingScheduler:
         """Admission through a prefix-cache hit: the first ``n_cached``
         pages of the row's block table map shared pages whose K/V is
         already resident (bitwise-identical by prefix determinism), so
-        only the tail ``ids[n_cached * page_size:]`` is prefilled — as a
-        multi-token DECODE over a B=1 view of the pool leaves, which
-        reads the shared prefix through the block table and writes only
-        private pages (every write position sits at or beyond the
-        boundary page).  Returns the last real token's logits row.
+        only the tail ``ids[n_cached * page_size:]`` is prefilled — as
+        one window (``Model.prefill_window``, one pass over the weights,
+        attention tiled over the window) over a B=1 view of the donated
+        pool, which reads the shared prefix through the block table and
+        writes only private pages (every write position sits at or
+        beyond the boundary page).  The span ``serve.prefill_hit`` runs
+        from the dispatch to the host's read of the logits row: the
+        stall the hit puts into every resident row.  Returns the last
+        real token's logits row.
 
         NOT a tick function: runs only from ``_admit`` (lint rule R6
         keeps cache traffic off the per-token path).
         """
         eng = self.eng
-        ps = self.page_size
-        start = n_cached * ps
+        start = n_cached * self.page_size
         tail = list(ids[start:])
         assert tail, "cache hit must leave a non-empty private tail"
-        # bucket the tail so the B=1 decode compiles per size class, not
-        # per length; pads write garbage above the final frontier (pos >=
-        # len is invalid by contract) into private/trash pages only
-        p = min(_bucket_len(len(tail), eng.max_len), eng.max_len - start)
-        with span("prefill", rid=sess.rid, tokens=len(ids),
-                              padded=p):
-            feed = jnp.asarray(
-                [tail + [eng.tok.pad_id] * (p - len(tail))], jnp.int32)
-            padded = np.zeros(self.max_pages, np.int32)
-            padded[:len(page_ids)] = page_ids
-            view = {
-                "len": jnp.asarray([start], jnp.int32),
-                "head": self.cache["head"],
-                "tail": self.cache["tail"],
-                "group": self.cache["group"],
-                "pages": jnp.asarray(padded)[None, :],
-            }
-            lg, view = self._decode(eng.params, view, feed)
-            # merge the written pool leaves back (the call donated the
-            # batch cache's); other rows' pages are untouched (the
-            # scatter only wrote this row's private pages)
-            cache = dict(self.cache)
-            cache["head"], cache["tail"] = view["head"], view["tail"]
-            cache["group"] = view["group"]
-            cache["len"] = cache["len"].at[slot].set(len(ids))
-            self.cache = cache
-        self._page_tbl[slot, :] = 0
-        self._page_tbl[slot, :len(page_ids)] = page_ids
+        # bucket the tail so the window compiles per size class, not per
+        # length (``_warm_hits`` compiles each class up front)
+        p = _bucket_len(len(tail), eng.max_len, self.page_size)
+        table = np.zeros((1, self.max_pages), np.int32)
+        table[0, :len(page_ids)] = page_ids
+        with span("prefill_hit", rid=sess.rid, tokens=len(tail), window=p):
+            row, self.cache = self._run_window(start, table, tail, p)
+            jax.block_until_ready(row)
+        cache = dict(self.cache)
+        cache["len"] = cache["len"].at[slot].set(len(ids))
+        self.cache = cache
+        self.n_prefill_windows += 1
+        self._page_tbl[slot, :] = table[0]
         self._n_pages_row[slot] = len(page_ids)
         self._n_shared_row[slot] = n_cached
         self._pages_dirty = True
-        return lg[0, len(tail) - 1]
+        return row
+
+    def _run_window(self, start: int, table: np.ndarray, tail: List[int],
+                    p: int):
+        """Prefill ``tail`` at positions ``start``.. of a B=1 view of the
+        pool with block table ``table`` (1, max_pages), padded to ``p``
+        tokens; pads write garbage above the final frontier into private
+        or trash pages only.  Returns (the last real token's logits row,
+        the batch cache with the written pool leaves)."""
+        # numpy first: a list would lower a conversion program per shape
+        feed = np.full((1, p), self.eng.tok.pad_id, np.int32)
+        feed[0, :len(tail)] = tail
+        view = {"len": jnp.asarray(np.asarray([start], np.int32)),
+                "head": self.cache["head"], "tail": self.cache["tail"],
+                "group": self.cache["group"], "pages": jnp.asarray(table)}
+        lg, view = self._prefill_window(self.eng.params, view,
+                                        jnp.asarray(feed),
+                                        jnp.asarray(np.int32(len(tail))))
+        # merge the written pool leaves back (the call donated the batch
+        # cache's); other rows' pages are untouched (the window wrote
+        # only this view's private pages)
+        cache = dict(self.cache)
+        cache["head"], cache["tail"] = view["head"], view["tail"]
+        cache["group"] = view["group"]
+        return lg[0], cache
 
     def _reset_vacant_lens(self) -> None:
         """Vacant slots' rows are garbage by contract, but every batched

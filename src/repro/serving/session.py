@@ -14,7 +14,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -147,6 +147,9 @@ class Session:
     # over re-admissions), and whether adopt() cloned a cached checker
     # snapshot instead of replaying the journal through advance()
     n_cached_tokens: int = 0
+    # (cached tokens, tail tokens) of the latest admission when it was a
+    # prefix-cache hit, else None
+    hit_window: Optional[Tuple[int, int]] = None
     cached_checker: bool = False
     mask_time: float = 0.0            # this request's checker time only
     mask_overlap: float = 0.0         # ... of which hidden under device

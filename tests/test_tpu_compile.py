@@ -18,7 +18,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs.base import ModelConfig
-from repro.kernels.decode_attention.kernel import decode_attention_pallas
+from repro.kernels.decode_attention.kernel import (WINDOW_NAME,
+                                                   decode_attention_pallas)
 from repro.kernels.masked_sample.kernel import (masked_argmax_pallas,
                                                 masked_argmax_pallas_packed)
 from repro.models import build_model, kvcache
@@ -28,6 +29,8 @@ from repro.serving.scheduler import _scatter_row_paged
 # slots, 64-token pages and max_len 1024
 B, G, D, PAGE, MAX_LEN, V = 8, 32, 64, 64, 1024, 100352
 N_PAGES = B * (MAX_LEN // PAGE) + 1
+# a custom call's instruction name, less its numeric suffix
+CUSTOM_CALL = re.compile(r"%([A-Za-z_]\w*?)(?:\.\d+)? = [^\n]*custom-call\(")
 
 
 @pytest.fixture(scope="module")
@@ -70,6 +73,65 @@ def test_paged_decode_attention_compiles(one_chip, s_win):
              _spec(one_chip, (N_PAGES, PAGE, G, D), jnp.bfloat16),
              _spec(one_chip, (B,), jnp.int32),
              _spec(one_chip, (B, MAX_LEN // PAGE), jnp.int32))
+
+
+# Yi-34B: 8 KV heads of 7 query heads each, d_head 128, in a pool of
+# 1,536 64-token pages over 6 layers, rows of max_len 12,288
+YI_G, YI_QH, YI_D, YI_POOL, YI_PAGES = 8, 7, 128, 1536 * 6, 12288 // PAGE
+
+
+@pytest.mark.parametrize("s_win", [1, 64, 128, 256, 512])
+def test_window_attention_compiles_at_yi_heads(one_chip, s_win):
+    """A prefix-cache hit's tail at every window bucket up to 512 (the
+    untiled kernel ran out of VMEM at 256), and plain decode (S=1), each
+    under its own name."""
+    fn = functools.partial(decode_attention_pallas, interpret=False)
+    compiled = _compile(
+        lambda q, k, v, ln, tbl: fn(q, k, v, ln, block_tables=tbl),
+        _spec(one_chip, (1, s_win, YI_G, YI_QH, YI_D), jnp.bfloat16),
+        _spec(one_chip, (YI_POOL, PAGE, YI_G, YI_D), jnp.bfloat16),
+        _spec(one_chip, (YI_POOL, PAGE, YI_G, YI_D), jnp.bfloat16),
+        _spec(one_chip, (1,), jnp.int32),
+        _spec(one_chip, (1, YI_PAGES), jnp.int32))
+    names = {m.group(1) for m in CUSTOM_CALL.finditer(compiled.as_text())}
+    assert names == {"decode_attention_pallas" if s_win == 1
+                     else WINDOW_NAME}
+
+
+def _attention_call(s_win, g, qh, d, b, n_pages, max_pages):
+    """The pallas_call equation the paged kernel traces to."""
+    fn = functools.partial(decode_attention_pallas, interpret=False)
+    jaxpr = jax.make_jaxpr(
+        lambda q, k, v, ln, tbl: fn(q, k, v, ln, block_tables=tbl))(
+        jax.ShapeDtypeStruct((b, s_win, g, qh, d), jnp.bfloat16),
+        jax.ShapeDtypeStruct((n_pages, PAGE, g, d), jnp.bfloat16),
+        jax.ShapeDtypeStruct((n_pages, PAGE, g, d), jnp.bfloat16),
+        jax.ShapeDtypeStruct((b,), jnp.int32),
+        jax.ShapeDtypeStruct((b, max_pages), jnp.int32)).jaxpr
+    todo = [jaxpr]
+    while todo:
+        for eqn in todo.pop().eqns:
+            if eqn.primitive.name == "pallas_call":
+                return eqn
+            todo += [getattr(p, "jaxpr", p) for p in eqn.params.values()
+                     if hasattr(p, "eqns") or hasattr(p, "jaxpr")]
+    raise AssertionError("no pallas_call")
+
+
+@pytest.mark.parametrize("heads", [(G, 1, D), (YI_G, YI_QH, YI_D)],
+                         ids=["stablelm-1.6b", "yi-34b"])
+def test_decode_step_attention_is_one_query_tile(heads):
+    """Plain decode (S=1) at both configurations' heads keeps the grid it
+    had before the window axis: (rows, cache tiles), with one query tile
+    per row and the trace name ``decode_attention_pallas``; a long
+    window splits into query tiles under the window's name."""
+    one = _attention_call(1, *heads, B, N_PAGES, MAX_LEN // PAGE)
+    assert one.params["grid_mapping"].grid == (B, MAX_LEN // PAGE)
+    assert one.params["name"] is None
+    win = _attention_call(512, *heads, 1, N_PAGES, MAX_LEN // PAGE)
+    n_q = win.params["grid_mapping"].grid[0]
+    assert n_q > 1 and 512 % n_q == 0
+    assert win.params["name"] == WINDOW_NAME
 
 
 def test_dense_decode_attention_compiles(one_chip):
@@ -172,6 +234,26 @@ def test_paged_decode_copies_no_pool(one_chip, tpu_programs, widths, s_win):
     compiled = decode.lower(
         params, cache, _spec(one_chip, (SLOTS, s_win), jnp.int32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    assert _pool_traffic(compiled, cache) == []
+    assert compiled.memory_analysis().alias_size_in_bytes >= \
+        _pool_bytes(cache)
+    assert m.kv_inplace_layers == 4
+
+
+@pytest.mark.parametrize("widths", ["stablelm-1.6b", "yi-34b"])
+def test_prefill_window_copies_no_pool(one_chip, tpu_programs, widths):
+    """A prefix-cache hit's tail window over a B=1 view of the donated
+    pool writes it in place, as the decode step does: no copy, slice or
+    write-back of a pool, the pool aliased in and out."""
+    m, params, cache = _serving(one_chip, WIDTHS[widths])
+    view = dict(cache, len=_spec(one_chip, (1,), jnp.int32),
+                pages=_spec(one_chip, (1, SERVE_LEN // PAGE), jnp.int32))
+    window = jax.jit(m.prefill_window, donate_argnums=(1,))
+    compiled = window.lower(
+        params, view, _spec(one_chip, (1, 256), jnp.int32),
+        _spec(one_chip, (), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and WINDOW_NAME in text
     assert _pool_traffic(compiled, cache) == []
     assert compiled.memory_analysis().alias_size_in_bytes >= \
         _pool_bytes(cache)
